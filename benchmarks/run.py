@@ -93,6 +93,8 @@ def main() -> None:
                          "in the repo root)")
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     suites = _load_suites()
     step = args.step if args.step is not None else int(
         os.environ.get("BENCH_STEP", _git_step()))

@@ -44,7 +44,7 @@ def main() -> None:
     # TrainSession owns periodic checkpointing (atomic, pruned) and
     # resumes from the latest checkpoint in ckpt_dir automatically
     trainer = TrainSession(
-        pipe, cfg, backend="jnp", ckpt_dir=ckpt_dir, ckpt_every=50,
+        pipe, cfg, backend="auto", ckpt_dir=ckpt_dir, ckpt_every=50,
         on_metrics=lambda m: (m.batches_seen % 50 == 0) and print(
             f"  batch {m.batches_seen}: {m.words_seen:,} words "
             f"(checkpointed)"))

@@ -136,9 +136,10 @@ def init_state(vocab_size: int, cfg: W2VConfig, seed: int = 0,
     c_in, s_in = quant.encode_nearest(jnp.asarray(cold_in), spec.cold_dtype)
     c_out, s_out = quant.encode_nearest(jnp.asarray(cold_out),
                                         spec.cold_dtype)
-    put = _cold_put(mesh, cold_in.shape[0])
+    put, hot_put = _cold_put(mesh, cold_in.shape[0]), _hot_put(mesh)
     return TrainState(
-        w_in=h_in, w_out=h_out, cold_in=put(c_in), cold_out=put(c_out),
+        w_in=hot_put(h_in), w_out=hot_put(h_out),
+        cold_in=put(c_in), cold_out=put(c_out),
         scale_in=None if s_in is None else put(s_in),
         scale_out=None if s_out is None else put(s_out))
 
@@ -149,6 +150,17 @@ def _cold_put(mesh: Optional[Mesh], cold_pad: int) -> Callable:
         return jnp.asarray
     from repro.distributed.sharding import vocab_shard_sharding
     sharding = vocab_shard_sharding(mesh, cold_pad)
+    return lambda arr: jax.device_put(jnp.asarray(arr), sharding)
+
+
+def _hot_put(mesh: Optional[Mesh]) -> Callable:
+    """device_put for the hot head: replicated over the mesh, the layout
+    the sharded step returns it in, so the first step compiles the same
+    program as every later one."""
+    if mesh is None:
+        return jnp.asarray
+    from jax.sharding import NamedSharding
+    sharding = NamedSharding(mesh, P())
     return lambda arr: jax.device_put(jnp.asarray(arr), sharding)
 
 
@@ -574,8 +586,9 @@ class TrainSession:
                                                 self.spec.hot_dtype)
                 tree = {"w_in": w_in, "w_out": w_out}
         if self.placement is not None:
-            self.state.w_in = tree["hot_in"]
-            self.state.w_out = tree["hot_out"]
+            hot_put = _hot_put(self.mesh)
+            self.state.w_in = hot_put(tree["hot_in"])
+            self.state.w_out = hot_put(tree["hot_out"])
             self.state.cold_in = tree["cold_in"]
             self.state.cold_out = tree["cold_out"]
             self.state.scale_in = tree.get("scale_in")

@@ -45,7 +45,9 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import os
 import queue
+import sys
 import threading
 import time
 from concurrent.futures import (BrokenExecutor, CancelledError, Executor,
@@ -75,6 +77,12 @@ _WORKER_BAGS = None
 def _proc_init(cfg: W2VConfig, sampler: NegativeSampler,
                placement=None, bag_table=None) -> None:
     global _WORKER_CFG, _WORKER_SAMPLER, _WORKER_PLACEMENT, _WORKER_BAGS
+    # workers do host numpy work only, and the accelerator belongs to the
+    # parent: should anything here touch JAX (unpickling the placement
+    # imports it), it may only ever reach the CPU backend
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
     _WORKER_CFG = cfg
     _WORKER_SAMPLER = sampler
     _WORKER_PLACEMENT = placement
@@ -165,9 +173,14 @@ class AsyncBatchingPipeline(BatchingPipeline):
     # -- executor ------------------------------------------------------------
     def _make_executor(self) -> Executor:
         if self.mode == "process":
+            import multiprocessing
             from concurrent.futures import ProcessPoolExecutor
+            # spawn, never fork: the parent has usually initialised the
+            # accelerator runtime by now, and a forked child inherits its
+            # handles and threads mid-flight (one process per chip)
             return ProcessPoolExecutor(
                 max_workers=self.workers, initializer=_proc_init,
+                mp_context=multiprocessing.get_context("spawn"),
                 initargs=(self.cfg, self.sampler, self.placement,
                           self.bag_table))
         from concurrent.futures import ThreadPoolExecutor
@@ -176,8 +189,8 @@ class AsyncBatchingPipeline(BatchingPipeline):
 
     def _warm(self, ex: Executor) -> None:
         """Spawn and initialize every process worker up front. Without
-        this, workers spawn lazily at the first submits: each spawn forks,
-        re-imports, and unpickles the cfg + alias table — one-time setup
+        this, workers spawn lazily at the first submits: each spawn starts
+        an interpreter, re-imports, and unpickles the cfg + alias table — one-time setup
         cost that lands inside the steady-state stats window and was
         billed to process-mode throughput (the BENCH_6 async_process
         regression). Thread pools have no per-worker state to warm."""

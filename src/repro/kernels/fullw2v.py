@@ -54,6 +54,7 @@ oracle scatter-adds (the GPU original has the same benign race).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Tuple
 
 import jax
@@ -168,17 +169,17 @@ def _window_update(ctx, out_rows, label, mask, lr):
     # ops → this module, so a top-level import would be circular
     from repro.core.sgns import stable_sigmoid
 
-    corr = jax.lax.dot_general(
-        ctx, out_rows, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (K, M)
+    # f32 contractions on the MXU: Mosaic's default precision rounds f32
+    # operands to bf16, which moved the tables ~0.2% of an update away
+    # from the f32 oracle on a v5e. The GEMMs are tiny and DMA-bound.
+    dot = functools.partial(jax.lax.dot_general,
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+    corr = dot(ctx, out_rows, (((1,), (1,)), ((), ())))    # (K, M)
     g = lr * (label - stable_sigmoid(corr))
     g = jnp.where(mask, g, 0.0)
-    d_ctx = jax.lax.dot_general(
-        g, out_rows, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (K, d)
-    d_out = jax.lax.dot_general(
-        g, ctx, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (M, d)
+    d_ctx = dot(g, out_rows, (((1,), (0,)), ((), ())))     # (K, d)
+    d_out = dot(g, ctx, (((0,), (0,)), ((), ())))          # (M, d)
     return d_ctx, d_out
 
 
@@ -213,34 +214,44 @@ def _scatter_window_ctx(ring, d_ctx, t, src0: int, *, w_f: int, r: int,
                                    + d_ctx[src0 + j:src0 + j + 1, :])
 
 
-def _ctx_valid(t, k_pad: int, *, w_f: int, length):
-    """(k_pad,) bool — which context slots of window t are real words.
-    Rebuilds the static offset list with iota (no captured constants):
-    j < w_f -> j - w_f;  j >= w_f -> j - w_f + 1 (skipping offset 0)."""
-    ji = jax.lax.iota(jnp.int32, k_pad)
-    offs_arr = jnp.where(ji < w_f, ji - w_f, ji - w_f + 1)
-    p_arr = t + offs_arr
-    valid = jnp.logical_and(p_arr >= 0, p_arr < length)
-    return jnp.logical_and(valid, ji < 2 * w_f)
+class _Idx:
+    """Row/column view of a per-sentence SMEM index block stored lane-dense
+    as ``(1, 1, rows * cols)``: ``view[r, c]`` (or ``view[r]`` when
+    ``cols == 1``) reads element ``r * cols + c``. Every read is one full
+    index tuple into the block — Mosaic refuses sub-ref views of SMEM
+    blocks — and the flat layout keeps the block's SMEM footprint at its
+    element count (a ``(L, N)`` block would pad N up to 128 lanes)."""
+
+    def __init__(self, ref, cols: int = 1):
+        self.ref, self.cols = ref, cols
+
+    def __getitem__(self, rc):
+        r, c = rc if isinstance(rc, tuple) else (rc, 0)
+        return self.ref[0, 0, r * self.cols + c]
 
 
 def _window_label_mask(t, k_pad: int, m_pad: int, *, w_f: int, n_neg: int,
                        length):
-    """Label + validity mask for a single window's (k_pad, m_pad) update."""
-    label = (jax.lax.broadcasted_iota(jnp.int32, (k_pad, m_pad), 1)
-             == 0).astype(jnp.float32)
-    out_valid = jax.lax.iota(jnp.int32, m_pad) < n_neg + 1
-    mask = jnp.logical_and(
-        _ctx_valid(t, k_pad, w_f=w_f, length=length)[:, None],
-        out_valid[None, :])
+    """Label + validity mask for a single window's (k_pad, m_pad) update.
+    Built directly in 2-D (Mosaic cannot reshape a 1-D bool vector into a
+    column); the static offset list is rebuilt from the row iota (no
+    captured constants): j < w_f -> j - w_f; j >= w_f -> j - w_f + 1
+    (skipping offset 0)."""
+    ji = jax.lax.broadcasted_iota(jnp.int32, (k_pad, m_pad), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (k_pad, m_pad), 1)
+    label = (ci == 0).astype(jnp.float32)
+    p_arr = t + jnp.where(ji < w_f, ji - w_f, ji - w_f + 1)
+    mask = ((p_arr >= 0) & (p_arr < length) & (ji < 2 * w_f)
+            & (ci < n_neg + 1))
     return label, mask
 
 
-def _seq_window(t, tokens_ref, negs_ref, w_out_tab, ring, ctx_blk, out_blk,
+def _seq_window(t, tok, neg, w_out_tab, ring, ctx_blk, out_blk,
                 sem, *, w_f: int, n_neg: int, r: int, length, L: int, lr):
     """One strictly-ordered window update (fetch → GEMMs → apply → write
     back). Shared by `_kernel` and `_kernel_tiled`'s strict fallback; `r` is
-    the caller's ring size (2*w_f+1 sequential, T+2*w_f tiled);
+    the caller's ring size (2*w_f+1 sequential, T+2*w_f tiled); ``tok`` /
+    ``neg`` are :class:`_Idx` views of the sentence's tokens / negatives;
     ``w_out_tab`` is a :class:`_Table` (split under fused gather)."""
     k = 2 * w_f
     m = n_neg + 1
@@ -252,11 +263,10 @@ def _seq_window(t, tokens_ref, negs_ref, w_out_tab, ring, ctx_blk, out_blk,
     _zero_rows(ctx_blk, k, k_pad)
 
     # ---- fetch output rows: target + shared negatives (paper §3.1) ----
-    tgt = tokens_ref[0, t]
+    tgt = tok[t]
     w_out_tab.load(tgt, out_blk.at[pl.ds(0, 1)], sem)
     for j in range(n_neg):
-        neg = negs_ref[0, t, j]
-        w_out_tab.load(neg, out_blk.at[pl.ds(1 + j, 1)], sem)
+        w_out_tab.load(neg[t, j], out_blk.at[pl.ds(1 + j, 1)], sem)
     _zero_rows(out_blk, m, m_pad)
 
     # ---- the window update: two tiny GEMMs on VMEM-resident data ----
@@ -273,8 +283,7 @@ def _seq_window(t, tokens_ref, negs_ref, w_out_tab, ring, ctx_blk, out_blk,
     out_blk[...] = out_rows + d_out
     w_out_tab.store(out_blk.at[pl.ds(0, 1)], tgt, sem)
     for j in range(n_neg):
-        neg = negs_ref[0, t, j]
-        w_out_tab.store(out_blk.at[pl.ds(1 + j, 1)], neg, sem)
+        w_out_tab.store(out_blk.at[pl.ds(1 + j, 1)], neg[t, j], sem)
 
 
 # ---------------------------------------------------------------------------
@@ -282,10 +291,10 @@ def _seq_window(t, tokens_ref, negs_ref, w_out_tab, ring, ctx_blk, out_blk,
 # ---------------------------------------------------------------------------
 
 def _kernel(
-    # --- scalar/SMEM inputs (per sentence block) ---
-    tokens_ref,    # (1, L) int32  SMEM
-    negs_ref,      # (1, L, N) int32 SMEM
-    length_ref,    # (1,) int32 SMEM
+    # --- scalar/SMEM inputs (per sentence block, lane-dense) ---
+    tokens_ref,    # (1, 1, L) int32 SMEM
+    negs_ref,      # (1, 1, L*N) int32 SMEM — window t's j-th at t*N + j
+    length_ref,    # (1, 1, 1) int32 SMEM
     lr_ref,        # (1,) f32 SMEM
     # --- HBM (ANY) inputs, aliased to outputs ---
     w_in_hbm,      # (V, d)
@@ -303,9 +312,10 @@ def _kernel(
     n_neg: int,
 ):
     """See module docstring; `_kernel_pipelined` adds §3.1-style prefetch."""
-    L = tokens_ref.shape[1]
+    L = tokens_ref.shape[2]
     r = 2 * w_f + 1
-    length = length_ref[0]
+    tok, neg = _Idx(tokens_ref), _Idx(negs_ref, n_neg)
+    length = length_ref[0, 0, 0]
     lr = lr_ref[0]
 
     def copy(src, dst):
@@ -315,13 +325,11 @@ def _kernel(
 
     def load_ring(q):
         """HBM w_in row tokens[q] -> ring slot q % r."""
-        tok = tokens_ref[0, q]
-        copy(w_in_out.at[pl.ds(tok, 1)], ring.at[pl.ds(q % r, 1)])
+        copy(w_in_out.at[pl.ds(tok[q], 1)], ring.at[pl.ds(q % r, 1)])
 
     def store_ring(p):
         """ring slot p % r -> HBM w_in row tokens[p] (write-through output)."""
-        tok = tokens_ref[0, p]
-        copy(ring.at[pl.ds(p % r, 1)], w_in_out.at[pl.ds(tok, 1)])
+        copy(ring.at[pl.ds(p % r, 1)], w_in_out.at[pl.ds(tok[p], 1)])
 
     # --- preload positions 0..w_f-1 ---
     def preload(q, _):
@@ -344,7 +352,7 @@ def _kernel(
                 store_ring(q - r)
             load_ring(q)
 
-        _seq_window(t, tokens_ref, negs_ref, _Table(w_out_out), ring,
+        _seq_window(t, tok, neg, _Table(w_out_out), ring,
                     ctx_blk, out_blk, sem, w_f=w_f, n_neg=n_neg, r=r,
                     length=length, L=L, lr=lr)
         return 0
@@ -392,14 +400,15 @@ def _kernel_pipelined(
     semantics to the sequential kernel, overlap in the common
     (collision-free) case.
     """
-    L = tokens_ref.shape[1]
+    L = tokens_ref.shape[2]
     d = w_in_hbm.shape[1]
     r = 2 * w_f + 1
     k = 2 * w_f
     m = n_neg + 1
     k_pad = ctx_blk.shape[0]
     m_pad = out_dbl.shape[1]
-    length = length_ref[0]
+    tok, neg = _Idx(tokens_ref), _Idx(negs_ref, n_neg)
+    length = length_ref[0, 0, 0]
     lr = lr_ref[0]
 
     def copy(src, dst, sem):
@@ -408,8 +417,8 @@ def _kernel_pipelined(
         cp.wait()
 
     def row_idx(t, j):
-        return jnp.where(j == 0, tokens_ref[0, t],
-                         negs_ref[0, t, jnp.maximum(j - 1, 0)])
+        """Output row j of window t (static j: 0 = target, else negative)."""
+        return tok[t] if j == 0 else neg[t, j - 1]
 
     def conflicts_prev(t, j):
         """Does row j of window t collide with any output row of window
@@ -434,12 +443,12 @@ def _kernel_pipelined(
                     sem_out.at[buf]).start()
 
     def load_ring(q):
-        copy(w_in_out.at[pl.ds(tokens_ref[0, q], 1)],
+        copy(w_in_out.at[pl.ds(tok[q], 1)],
              ring.at[pl.ds(q % r, 1)], sem_ring)
 
     def store_ring(p):
         copy(ring.at[pl.ds(p % r, 1)],
-             w_in_out.at[pl.ds(tokens_ref[0, p], 1)], sem_ring)
+             w_in_out.at[pl.ds(tok[p], 1)], sem_ring)
 
     # --- preload ring positions 0..w_f-1 and prefetch window 0 rows ---
     def preload(q, _):
@@ -535,15 +544,15 @@ def _kernel_pipelined(
 # ---------------------------------------------------------------------------
 
 def _kernel_tiled(
-    # --- scalar/SMEM inputs (per sentence block) ---
-    tokens_ref,    # (1, L) int32 SMEM
-    negs_ref,      # (1, L, N) int32 SMEM
-    length_ref,    # (1,) int32 SMEM
+    # --- scalar/SMEM inputs (per sentence block, lane-dense) ---
+    tokens_ref,    # (1, 1, L) int32 SMEM
+    negs_ref,      # (1, 1, L*N) int32 SMEM
+    length_ref,    # (1, 1, 1) int32 SMEM
     lr_ref,        # (1,) f32 SMEM
-    uniq_ref,      # (1, nt, T*m) int32 SMEM — compacted unique output rows
-    scat_ref,      # (1, nt, T*m) int32 SMEM — slot -> uniq column
-    ucount_ref,    # (1, nt) int32 SMEM — valid uniq columns per tile
-    strict_ref,    # (1, nt) int32 SMEM — 1: sequential fallback tile
+    uniq_ref,      # (1, 1, nt*T*m) int32 SMEM — compacted unique output rows
+    scat_ref,      # (1, 1, nt*T*m) int32 SMEM — slot -> uniq column
+    ucount_ref,    # (1, 1, nt) int32 SMEM — valid uniq columns per tile
+    strict_ref,    # (1, 1, nt) int32 SMEM — 1: sequential fallback tile
     # --- HBM (ANY) inputs + aliased outputs + scratch, layout depends on
     # hot_rows/prefetch (see the unpacking right below):
     #   hot_rows == 0: w_in_hbm, w_out_hbm, w_in_out, w_out_out
@@ -592,8 +601,8 @@ def _kernel_tiled(
         ring, ctx_tile, out_uniq, out_exp, ctx_win, out_win, sem = scratch
         sem_pf = None
 
-    L = tokens_ref.shape[1]
-    nt = uniq_ref.shape[1]
+    L = tokens_ref.shape[2]
+    nt = ucount_ref.shape[2]
     rt = tile + 2 * w_f            # ring positions covering the whole tile
     k = 2 * w_f
     m = n_neg + 1
@@ -602,17 +611,18 @@ def _kernel_tiled(
     gk_pad = ctx_tile.shape[0]
     gm_pad = out_exp.shape[0]
     u_pad = out_uniq.shape[1]
-    k_pad = ctx_win.shape[0]
-    m_pad = out_win.shape[0]
     d = ring.shape[-1]
-    length = length_ref[0]
+    tok, neg = _Idx(tokens_ref), _Idx(negs_ref, n_neg)
+    uniq, scat = _Idx(uniq_ref, M), _Idx(scat_ref, M)
+    ucount, strict_bit = _Idx(ucount_ref), _Idx(strict_ref)
+    length = length_ref[0, 0, 0]
     lr = lr_ref[0]
 
     def load_ring(q):
-        w_in_tab.load(tokens_ref[0, q], ring.at[pl.ds(q % rt, 1)], sem)
+        w_in_tab.load(tok[q], ring.at[pl.ds(q % rt, 1)], sem)
 
     def store_ring(p):
-        w_in_tab.store(ring.at[pl.ds(p % rt, 1)], tokens_ref[0, p], sem)
+        w_in_tab.store(ring.at[pl.ds(p % rt, 1)], tok[p], sem)
 
     def was_prefetched(ti, c):
         """Was uniq column c of tile ti prefetched during tile ti-1? A pure
@@ -623,14 +633,14 @@ def _kernel_tiled(
         tc = jnp.clip(ti, 0, nt - 1)
         pv = jnp.maximum(tc - 1, 0)
         ok = ((ti > 0) & (ti < nt) & (ti * tile < length)
-              & (strict_ref[0, tc] == 0) & (strict_ref[0, pv] == 0)
-              & (c < ucount_ref[0, tc]))
-        idx = uniq_ref[0, tc, c]
+              & (strict_bit[tc] == 0) & (strict_bit[pv] == 0)
+              & (c < ucount[tc]))
+        idx = uniq[tc, c]
         hit = jnp.bool_(False)
         for cc in range(M):
             hit = jnp.logical_or(
-                hit, jnp.logical_and(cc < ucount_ref[0, pv],
-                                     idx == uniq_ref[0, pv, cc]))
+                hit, jnp.logical_and(cc < ucount[pv],
+                                     idx == uniq[pv, cc]))
         return jnp.logical_and(ok, ~hit)
 
     # --- preload positions 0..w_f-1 ---
@@ -666,7 +676,7 @@ def _kernel_tiled(
 
     def tile_step(i, _):
         t0 = i * tile
-        strict = strict_ref[0, i] != 0
+        strict = strict_bit[i] != 0
         # double-buffer parity: tile i's rows live in half i % 2 (half 0
         # always when the prefetch stage is off)
         buf = jax.lax.rem(i, 2) if prefetch else 0
@@ -681,7 +691,7 @@ def _kernel_tiled(
                 @pl.when(t < length)
                 def _():
                     advance_window(t)
-                    _seq_window(t, tokens_ref, negs_ref, w_out_tab, ring,
+                    _seq_window(t, tok, neg, w_out_tab, ring,
                                 ctx_win, out_win, sem, w_f=w_f, n_neg=n_neg,
                                 r=rt, length=length, L=L, lr=lr)
 
@@ -693,7 +703,7 @@ def _kernel_tiled(
             # tile instead of one per row (paper §3.1 amortization). Rows
             # already in flight from the previous tile's prefetch stage
             # only need their wait.
-            u = ucount_ref[0, i]
+            u = ucount[i]
             for c in range(M):
                 fetch = c < u
                 if prefetch:
@@ -701,7 +711,7 @@ def _kernel_tiled(
 
                 @pl.when(fetch)
                 def _(c=c):
-                    w_out_tab.start_load(uniq_ref[0, i, c],
+                    w_out_tab.start_load(uniq[i, c],
                                          out_uniq.at[buf, pl.ds(c, 1)], sem)
 
                 @pl.when(~(c < u))
@@ -716,13 +726,13 @@ def _kernel_tiled(
 
                     @pl.when(pf)
                     def _(c=c):
-                        w_out_tab.wait_load(uniq_ref[0, i, c],
+                        w_out_tab.wait_load(uniq[i, c],
                                             out_uniq.at[buf, pl.ds(c, 1)],
                                             sem_pf.at[buf])
 
                 @pl.when(fetch)
                 def _(c=c):
-                    w_out_tab.wait_load(uniq_ref[0, i, c],
+                    w_out_tab.wait_load(uniq[i, c],
                                         out_uniq.at[buf, pl.ds(c, 1)], sem)
             if u_pad > M:
                 out_uniq[buf, pl.ds(M, u_pad - M), :] = jnp.zeros(
@@ -738,7 +748,7 @@ def _kernel_tiled(
                     @pl.when(was_prefetched(i + 1, c))
                     def _(c=c):
                         w_out_tab.start_load(
-                            uniq_ref[0, nxt, c],
+                            uniq[nxt, c],
                             out_uniq.at[1 - buf, pl.ds(c, 1)],
                             sem_pf.at[1 - buf])
 
@@ -754,27 +764,26 @@ def _kernel_tiled(
 
                 # expand the group's slots from the (fresh) compacted rows
                 for sj in range(wn * m):
-                    col = scat_ref[0, i, w0 * m + sj]
+                    col = scat[i, w0 * m + sj]
                     out_exp[pl.ds(sj, 1), :] = out_uniq[buf,
                                                         pl.ds(col, 1), :]
                 _zero_rows(out_exp, wn * m, gm_pad)
 
                 # two MXU-shaped GEMMs with a block-diagonal mask (window
                 # w's context rows pair only with window w's slots)
-                ri = jax.lax.iota(jnp.int32, gk_pad)
+                # (built directly in 2-D, like `_window_label_mask`)
+                ri = jax.lax.broadcasted_iota(jnp.int32, (gk_pad, gm_pad), 0)
+                ci = jax.lax.broadcasted_iota(jnp.int32, (gk_pad, gm_pad), 1)
                 jr = jax.lax.rem(ri, k)
                 win_r = jax.lax.div(ri, k)
                 offs_arr = jnp.where(jr < w_f, jr - w_f, jr - w_f + 1)
                 p_arr = base + win_r + offs_arr
                 row_valid = ((p_arr >= 0) & (p_arr < length)
                              & (base + win_r < length) & (ri < wn * k))
-                ci = jax.lax.iota(jnp.int32, gm_pad)
                 win_c = jax.lax.div(ci, m)
                 col_valid = (base + win_c < length) & (ci < wn * m)
                 label = (jax.lax.rem(ci, m) == 0).astype(jnp.float32)
-                label = jnp.broadcast_to(label[None, :], (gk_pad, gm_pad))
-                mask = (row_valid[:, None] & col_valid[None, :]
-                        & (win_r[:, None] == win_c[None, :]))
+                mask = row_valid & col_valid & (win_r == win_c)
 
                 d_ctx, d_out = _window_update(ctx_tile[...], out_exp[...],
                                               label, mask, lr)
@@ -787,7 +796,7 @@ def _kernel_tiled(
                 # compact output deltas through the scatter map (invalid
                 # slots carry zero gradient)
                 for sj in range(wn * m):
-                    col = scat_ref[0, i, w0 * m + sj]
+                    col = scat[i, w0 * m + sj]
                     out_uniq[buf, pl.ds(col, 1), :] = (
                         out_uniq[buf, pl.ds(col, 1), :] + d_out[sj:sj + 1, :])
 
@@ -826,12 +835,12 @@ def _kernel_tiled(
                 @pl.when(c < u)
                 def _(c=c):
                     w_out_tab.start_store(out_uniq.at[buf, pl.ds(c, 1)],
-                                          uniq_ref[0, i, c], sem)
+                                          uniq[i, c], sem)
             for c in range(M):
                 @pl.when(c < u)
                 def _(c=c):
                     w_out_tab.wait_store(out_uniq.at[buf, pl.ds(c, 1)],
-                                         uniq_ref[0, i, c], sem)
+                                         uniq[i, c], sem)
         return 0
 
     def guarded_tile(i, c):
@@ -859,6 +868,29 @@ def _kernel_tiled(
 # Host-side entry points
 # ---------------------------------------------------------------------------
 
+_HBM = pl.BlockSpec(memory_space=pl.ANY)     # tables: DMA'd by hand
+_SCALAR = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole (1,) lr
+
+
+def _per_sentence(*arrays):
+    """Lane-dense per-sentence SMEM operands: each ``(S, ...)`` int array
+    becomes ``(S, 1, n)`` (``n`` = elements per sentence) with a ``(1, 1,
+    n)`` block per grid step. The unit axis makes the block's last two
+    dims equal the array's — the TPU block-shape rule for a block that is
+    not (8, 128)-aligned — and the flat minor axis keeps each block's
+    SMEM bill at its element count, so the paper's L = 1000 fits (a
+    ``(1, L, N)`` block pads N up to 128 lanes: 512 KiB per buffer).
+    Returns ``(operands, block_specs)``; kernels read the blocks through
+    :class:`_Idx`."""
+    ops, specs = [], []
+    for x in arrays:
+        n = math.prod(x.shape[1:])
+        ops.append(x.reshape(x.shape[0], 1, n))
+        specs.append(pl.BlockSpec((1, 1, n), lambda s: (s, 0, 0),
+                                  memory_space=pltpu.SMEM))
+    return ops, specs
+
+
 def fullw2v_pallas(
     w_in: jax.Array,     # (V, d) f32
     w_out: jax.Array,    # (V, d) f32
@@ -871,7 +903,7 @@ def fullw2v_pallas(
     pipeline: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """One FULL-W2V training pass over a batch of sentences."""
-    S, L = tokens.shape
+    S = tokens.shape[0]
     n_neg = negs.shape[-1]
     V, d = w_in.shape
     assert d % LANE == 0, f"embedding dim {d} must be a multiple of {LANE}"
@@ -900,24 +932,12 @@ def fullw2v_pallas(
             pltpu.VMEM((m_pad, d), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ]
+    idx, idx_specs = _per_sentence(tokens, negs, lengths)
     out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, L), lambda s: (s, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, L, n_neg), lambda s: (s, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda s: (s,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
+        in_specs=[*idx_specs, _SCALAR, _HBM, _HBM],
+        out_specs=[_HBM, _HBM],
         out_shape=[
             jax.ShapeDtypeStruct((V, d), w_in.dtype),
             jax.ShapeDtypeStruct((V, d), w_out.dtype),
@@ -925,7 +945,7 @@ def fullw2v_pallas(
         scratch_shapes=scratch,
         input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
-    )(tokens, negs, lengths, lr_arr, w_in, w_out)
+    )(*idx, lr_arr, w_in, w_out)
     return out[0], out[1]
 
 
@@ -978,32 +998,13 @@ def fullw2v_pallas_tiled(
         pltpu.VMEM((dims["out_win"], d), jnp.float32),   # strict path
         pltpu.SemaphoreType.DMA,
     ]
+    (tk, ng, ln, *plan), specs = _per_sentence(
+        tokens, negs, lengths, uniq, scatter, ucount, strict)
     out = pl.pallas_call(
         kernel,
         grid=(S,),
-        in_specs=[
-            pl.BlockSpec((1, L), lambda s: (s, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, L, n_neg), lambda s: (s, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda s: (s,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, nt, M), lambda s: (s, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, nt, M), lambda s: (s, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, nt), lambda s: (s, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, nt), lambda s: (s, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
+        in_specs=[*specs[:3], _SCALAR, *specs[3:], _HBM, _HBM],
+        out_specs=[_HBM, _HBM],
         out_shape=[
             jax.ShapeDtypeStruct((V, d), w_in.dtype),
             jax.ShapeDtypeStruct((V, d), w_out.dtype),
@@ -1011,8 +1012,7 @@ def fullw2v_pallas_tiled(
         scratch_shapes=scratch,
         input_output_aliases={8: 0, 9: 1},
         interpret=interpret,
-    )(tokens, negs, lengths, lr_arr, uniq, scatter, ucount, strict,
-      w_in, w_out)
+    )(tk, ng, ln, lr_arr, *plan, w_in, w_out)
     return out[0], out[1]
 
 
@@ -1075,36 +1075,13 @@ def fullw2v_pallas_tiled_fused(
         pltpu.SemaphoreType.DMA,                             # strict/ring
         pltpu.SemaphoreType.DMA((2,)),                       # per-half
     ]
+    (tk, ng, ln, *plan), specs = _per_sentence(
+        tokens, negs, lengths, uniq, scatter, ucount, strict)
     out = pl.pallas_call(
         kernel,
         grid=(S,),
-        in_specs=[
-            pl.BlockSpec((1, L), lambda s: (s, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, L, n_neg), lambda s: (s, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda s: (s,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, nt, M), lambda s: (s, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, nt, M), lambda s: (s, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, nt), lambda s: (s, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, nt), lambda s: (s, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
+        in_specs=[*specs[:3], _SCALAR, *specs[3:], *[_HBM] * 4],
+        out_specs=[_HBM] * 4,
         out_shape=[
             jax.ShapeDtypeStruct((hot, d), hot_in.dtype),
             jax.ShapeDtypeStruct((hot, d), hot_out.dtype),
@@ -1114,6 +1091,5 @@ def fullw2v_pallas_tiled_fused(
         scratch_shapes=scratch,
         input_output_aliases={8: 0, 9: 1, 10: 2, 11: 3},
         interpret=interpret,
-    )(tokens, negs, lengths, lr_arr, uniq, scatter, ucount, strict,
-      hot_in, hot_out, got_in, got_out)
+    )(tk, ng, ln, lr_arr, *plan, hot_in, hot_out, got_in, got_out)
     return out[0], out[1], out[2], out[3]

@@ -254,8 +254,6 @@ def _jitted_dp_update(name: str, static: KernelStatic, dtype: str,
     pmean-average. Sub-f32 storage decodes before and stochastically
     re-encodes after the average — the key is replicated, so every shard
     rounds the identical averaged values to the identical storage bits."""
-    from jax.experimental.shard_map import shard_map
-
     local = traceable_update(name, static)
     mixed = dtype != "float32"
 
@@ -282,11 +280,11 @@ def _jitted_dp_update(name: str, static: KernelStatic, dtype: str,
         round_key=P() if has_key else None,
         static_ctx=P(axis_name) if has_doc else None,
         bags=P(axis_name) if has_bags else None)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_update, mesh=mesh,
         in_specs=(P(), P(), step_specs),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=(0, 1))
 
@@ -298,8 +296,6 @@ def _jitted_vs_update(name: str, static: KernelStatic, spec: TableSpec,
     """The vocab-sharded step under shard_map: hot replicas P(), cold
     tables (and int8 scales) row-sharded over ``axis_name``, the exchange
     plan sharded by requester."""
-    from jax.experimental.shard_map import shard_map
-
     run = _vocab_sharded_run(name, static, placement, axis_name=axis_name,
                              exchange=spec.exchange, spec=spec)
     plan_spec = P(axis_name) if static.tile > 1 else None
@@ -313,13 +309,13 @@ def _jitted_vs_update(name: str, static: KernelStatic, spec: TableSpec,
         static_ctx=P(axis_name) if has_doc else None,
         bags=P(axis_name) if has_bags else None)
     scale_spec = P(axis_name) if spec.needs_scales else None
-    sharded = shard_map(
+    sharded = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(), P(), P(axis_name), P(axis_name),
                   scale_spec, scale_spec, step_specs),
         out_specs=(P(), P(), P(axis_name), P(axis_name),
                    scale_spec, scale_spec),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=(0, 1, 2, 3, 4, 5))
 

@@ -65,7 +65,9 @@ def main() -> int:
     import jax
     import numpy as np
 
+    from repro.launch.cache import enable_compile_cache
     from repro.serve import EmbeddingIndex, EmbeddingServer, SnapshotWatcher
+    enable_compile_cache()
     from repro.serve.query import dense_topk
 
     mesh = None

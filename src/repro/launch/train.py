@@ -298,6 +298,8 @@ def main() -> int:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "") +
             f" --xla_force_host_platform_device_count={n_shards}")
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     return args.fn(args)
 
 

@@ -92,8 +92,6 @@ def _moe_shardmap(cfg: ArchConfig, p: Params, x: jax.Array,
     EXPERIMENTS.md §Perf iteration 3); local scatter under shard_map is the
     production formulation (cf. MaxText/praxis).
     """
-    from jax.experimental.shard_map import shard_map
-
     mesh = rules.mesh
     moe = cfg.moe
     b, s, d = x.shape
@@ -124,12 +122,12 @@ def _moe_shardmap(cfg: ArchConfig, p: Params, x: jax.Array,
         out = jax.lax.psum(out.astype(jnp.bfloat16), "model")
         return out.reshape(xb.shape).astype(xb.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(batch_axes, None, None), P(), gate_spec, gate_spec,
                   down_spec),
         out_specs=P(batch_axes, None, None),
-        check_rep=False)
+        check_vma=False)
     out = fn(x, p["w_router"], p["we_gate"], p["we_up"], p["we_down"])
     if moe.dense_residual:
         out = out + mlp_block(p["residual"], x.reshape(b * s, d),

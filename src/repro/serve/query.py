@@ -87,7 +87,6 @@ def make_topk_fn(placement: VocabPlacement, mesh, mode: str = "nn",
     ``(B, 3)`` for ``mode="analogy"``; out-of-range/padded query slots
     are tolerated (clipped gathers) — callers mask their results.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n, hot_n, v = placement.n_shards, placement.hot, placement.vocab_size
@@ -124,11 +123,11 @@ def make_topk_fn(placement: VocabPlacement, mesh, mode: str = "nn",
         g_id = jnp.moveaxis(g_id, 0, 1).reshape(ids.shape[0], n * k)
         return _rank(g_sc, g_id, k)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P("data"), P()),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded)
 

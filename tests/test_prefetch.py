@@ -185,8 +185,7 @@ def test_make_pipeline_selects_by_config():
 
 def test_process_mode_matches_sync(subproc):
     """Process workers (fresh interpreters, no shared state) still emit the
-    bit-identical stream. Run in a subprocess with no jax imported so the
-    pool fork never races XLA threads."""
+    bit-identical stream. Run in a subprocess with no jax imported."""
     r = subproc("""
         import numpy as np
         from repro.configs.w2v import smoke
@@ -218,8 +217,7 @@ def test_killed_process_worker_heals_bit_identical(subproc):
     """SIGKILLing a process-pool worker breaks the whole pool
     (BrokenProcessPool): the pipeline must rebuild it and recompute the
     owed batches — the emitted stream stays bit-identical to sync
-    (DESIGN.md §9). Run in a subprocess with no jax imported so the pool
-    fork never races XLA threads."""
+    (DESIGN.md §9). Run in a subprocess with no jax imported."""
     r = subproc("""
         import os, signal
         import numpy as np
@@ -254,6 +252,55 @@ def test_killed_process_worker_heals_bit_identical(subproc):
     """)
     assert r.returncode == 0, r.stderr
     assert "HEAL_OK" in r.stdout
+
+
+# set in the test process only: a spawned worker imports this module fresh
+# and sees False, a forked one would inherit True
+_PARENT_ONLY = False
+
+
+def _worker_probe():
+    """Runs inside a pool worker after real finalize work."""
+    import os
+    import sys
+    jax_backend = False
+    if "jax" in sys.modules:
+        from jax._src import xla_bridge
+        jax_backend = xla_bridge.backends_are_initialized()
+    return _PARENT_ONLY, jax_backend, os.environ.get("JAX_PLATFORMS")
+
+
+def test_process_workers_spawn_and_never_start_jax(monkeypatch):
+    """Process workers start from a fresh interpreter (spawn, not fork —
+    the parent may hold the accelerator) and finalize batches without ever
+    initialising a JAX backend; any JAX use there is pinned to the CPU."""
+    from repro.data import prefetch
+    from repro.distributed.vocab_placement import VocabPlacement
+
+    # the worker must pin itself, not inherit the test run's setting
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    globals()["_PARENT_ONLY"] = True
+    try:
+        cfg = _cfg(vocab_shard=True)
+        apipe = AsyncBatchingPipeline(_corpus(), cfg, workers=1, depth=1,
+                                      mode="process")
+        # a placement makes the worker import repro.distributed (and jax)
+        apipe.placement = VocabPlacement.plan(apipe.vocab.counts, 2,
+                                              hot_frac=0.2)
+        packed = next(apipe._packed(32, 0))
+        ex = apipe._make_executor()
+        try:
+            batch = ex.submit(prefetch._proc_finalize, packed, 0).result()
+            assert batch.exchange is not None
+            inherited, jax_backend, platforms = ex.submit(
+                _worker_probe).result()
+        finally:
+            ex.shutdown(wait=True)
+    finally:
+        globals()["_PARENT_ONLY"] = False
+    assert inherited is False, "worker was forked from the parent"
+    assert jax_backend is False, "worker initialised a JAX backend"
+    assert platforms == "cpu"
 
 
 def test_dead_producer_surfaces_as_pipeline_fault(monkeypatch):

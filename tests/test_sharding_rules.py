@@ -9,10 +9,9 @@ from repro.models import lm
 
 
 def _mesh(multi_pod=False):
-    # jax 0.4.37 spells AbstractMesh as a tuple of (name, size) pairs
     if multi_pod:
-        return AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
-    return AbstractMesh((("data", 16), ("model", 16)))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_resolve_divisibility():

@@ -33,7 +33,6 @@ ALLOWLIST = {
     # trivial re-export __init__.py facades; every submodule is tested
     # directly by its own test module
     "repro.core": "trivial re-export __init__ (submodules tested)",
-    "repro.data": "trivial re-export __init__ (submodules tested)",
     # exercised through a covered importer, not imported by tests directly
     "repro.models.moe":
         "driven through repro.models.lm (tested) + bench_lm_step",
