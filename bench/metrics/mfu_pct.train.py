@@ -1,0 +1,7 @@
+"""``harness.readers.mfu_pct``, read in the training cells
+that report ``words_per_s``."""
+from harness import readers
+
+
+def read(rec):
+    return readers.mfu_pct(rec)

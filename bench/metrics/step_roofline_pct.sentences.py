@@ -1,0 +1,7 @@
+"""``harness.readers.step_roofline_pct``, read in the sentence-delimited
+cells, which report ``words_per_s.sentences``."""
+from harness import readers
+
+
+def read(rec):
+    return readers.step_roofline_pct(rec)

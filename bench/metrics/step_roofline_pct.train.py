@@ -1,0 +1,7 @@
+"""``harness.readers.step_roofline_pct``, read in the training cells
+that report ``words_per_s``."""
+from harness import readers
+
+
+def read(rec):
+    return readers.step_roofline_pct(rec)
