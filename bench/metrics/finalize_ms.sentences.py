@@ -1,0 +1,10 @@
+"""Mean host finalize per window batch: the ``repro.pipeline.finalize``
+span (negatives and tile plan, in a ``data/prefetch.py`` worker) keyed
+by the batch, in ms (layer: host pipeline, ``data/batching.py``), read
+in the sentence-delimited cells, which report
+``words_per_s.sentences``."""
+from harness import spans
+
+
+def read(rec):
+    return spans.mean_ms(rec, "repro.pipeline.finalize")

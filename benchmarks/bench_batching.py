@@ -74,7 +74,6 @@ def run() -> List[str]:
             f"words_per_sec={wps:.0f} "
             f"speedup_vs_sync={wps / max(wps_sync, 1e-9):.2f} "
             f"workers={BENCH_WORKERS} "
-            f"mean_queue_depth={apipe.prefetch.mean_depth:.2f} "
             f"max_in_flight={apipe.prefetch.max_in_flight} "
             f"bitwise_match_sync={match:.0f}"))
     return rows

@@ -84,16 +84,16 @@ def run() -> List[str]:
 
 def _overlap_rows() -> List[str]:
     """Overlap efficiency of the async host pipeline under a real training
-    session (DESIGN.md §4.1): device-busy fraction (1 - time blocked on the
-    host pipeline) and prefetch queue depth, sync vs async on the same
+    session (DESIGN.md §4.1): the share of time blocked on the host
+    pipeline and the finalize time a batch, sync vs async on the same
     seed — the streams (and final tables) are bit-identical, only the wall
     clock moves.
 
     CPU-container caveat (DESIGN.md §6): the "device" here is XLA-CPU
     sharing cores with the workers, so the update dominates and words/sec
     moves within noise; the discriminating signal on this box is
-    ``fetch_wait_frac`` (host-stall share of wall time) and the queue
-    depth. On a real accelerator the host share is the whole story —
+    ``fetch_wait_frac`` (host-stall share of wall time) and the
+    finalize time a batch. On a real accelerator the host share is the whole story —
     that is what the batching/async rows measure in isolation."""
     import dataclasses
     import os
@@ -112,14 +112,14 @@ def _overlap_rows() -> List[str]:
         pipe = make_pipeline(corpus, cfg)
         sess = TrainSession(pipe, cfg, backend="jnp")
         sess.train(max_batches=1)       # compile outside the clock
+        batches0 = sess.state.batches_seen
         sess.train(epochs=1)
-        depth = (f" mean_queue_depth={pipe.prefetch.mean_depth:.2f}"
-                 if n_workers else "")
+        host = sess.host_report(sess.state.batches_seen - batches0)
         rows.append(fmt_row(
             f"throughput/overlap_{name}", sess.wall_seconds * 1e6,
             f"words_per_sec={sess.words_per_sec:.0f} "
-            f"device_busy_frac={sess.device_busy_frac:.3f} "
-            f"fetch_wait_frac={1 - sess.device_busy_frac:.3f}" + depth))
+            f"fetch_wait_frac={host['host_wait']:.3f} "
+            f"finalize_ms={host['finalize_ms']:.2f}"))
     return rows
 
 

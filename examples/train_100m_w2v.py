@@ -51,10 +51,14 @@ def main() -> None:
     if trainer.resumed_step is not None:
         print(f"resumed from checkpoint batch {trainer.resumed_step}")
     t0 = time.time()
+    batches0 = trainer.state.batches_seen
     trainer.train(max_batches=args.batches)
+    host = trainer.host_report(trainer.state.batches_seen - batches0)
     print(f"trained {trainer.state.words_seen:,} words in "
           f"{time.time() - t0:.0f}s -> {trainer.words_per_sec:,.0f} words/s "
-          f"(device busy {trainer.device_busy_frac:.0%})")
+          f"(host wait {host['host_wait']:.0%}, finalize "
+          f"{host['finalize_ms']:.1f} ms a batch, "
+          f"{host['neg_draws_per_word']:.1f} negative draws a word)")
     print("final checkpoint:", trainer.save_checkpoint())
     emb = trainer.embeddings()
     print("embedding norms: mean", float(np.linalg.norm(emb, axis=1).mean()))

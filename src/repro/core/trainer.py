@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import tracing
 from repro.configs.w2v import W2VConfig
 from repro.data.batching import Batch, BatchingPipeline
 from repro.kernels import ops, quant, registry
@@ -297,19 +298,25 @@ class TrainSession:
         sub-f32 storage the step also carries the batch's rounding key —
         a pure function of (seed, epoch, batch index), like the
         subsample/negative draws, so stochastic storage rounding replays
-        bit-identically at any worker count."""
-        if self.placement is not None:
-            ex = getattr(batch, "exchange", None)
-            if ex is None or ex.placement != self.placement:
-                from repro.distributed.vocab_placement import plan_exchange
-                ex = plan_exchange(batch, self.placement)
-            step = ex.step_inputs(lr)
-        else:
-            step = batch.step_inputs(lr)
-        if self.spec.is_mixed:
-            key = quant.round_key(self.cfg.seed, batch.epoch, batch.index)
-            step = dataclasses.replace(step, round_key=jnp.asarray(key))
-        return step
+        bit-identically at any worker count. The work, the transfer's
+        start included, is one ``repro.session.put`` span keyed by the
+        batch's ``(epoch, index)``."""
+        with tracing.span("repro.session.put",
+                          key=(batch.epoch, batch.index)):
+            if self.placement is not None:
+                ex = getattr(batch, "exchange", None)
+                if ex is None or ex.placement != self.placement:
+                    from repro.distributed.vocab_placement import \
+                        plan_exchange
+                    ex = plan_exchange(batch, self.placement)
+                step = ex.step_inputs(lr)
+            else:
+                step = batch.step_inputs(lr)
+            if self.spec.is_mixed:
+                key = quant.round_key(self.cfg.seed, batch.epoch,
+                                      batch.index)
+                step = dataclasses.replace(step, round_key=jnp.asarray(key))
+            return step
 
     # -- train ---------------------------------------------------------------
     def train_batch(self, batch: Batch,
@@ -319,49 +326,56 @@ class TrainSession:
         device_put) :class:`StepInputs` from the prefetch path — its lr was
         computed from the projected word count, which equals
         ``current_lr()`` exactly because word counts are known host-side
-        ahead of training."""
-        lr = self.current_lr()
-        skipped = ((self.state.epoch, self.state.epoch_batch)
-                   in self.poison_skip)
-        if skipped:
-            self.batches_skipped += 1
-            log.warning(
-                "skipping poison batch (epoch %d, batch %d) — counters "
-                "advance, tables untouched (%d skipped so far)",
-                self.state.epoch, self.state.epoch_batch,
-                self.batches_skipped)
-        elif step is None:
-            step = self._make_step(batch, lr)
-        elif self.placement is not None and not step.has_vocab_shard:
-            # a plain pre-built step carries un-remapped global ids; the
-            # sharded path needs the exchange plan, so rebuild from the
-            # host batch rather than crash (or silently corrupt) below
-            step = self._make_step(batch, lr)
-        if not skipped:
-            out = ops.step(self._tables(), step, self.cfg,
-                           backend=self._requested_backend, mesh=self.mesh)
-            st = self.state
-            st.w_in, st.w_out = out.w_in, out.w_out
-            st.cold_in, st.cold_out = out.cold_in, out.cold_out
-            st.scale_in, st.scale_out = out.scale_in, out.scale_out
-        self.state.words_seen += batch.n_words
-        self.state.batches_seen += 1
-        self.state.epoch_batch += 1
-        self.fetch_seconds += fetch_seconds
-        metrics = StepMetrics(
-            epoch=self.state.epoch, batches_seen=self.state.batches_seen,
-            words_seen=self.state.words_seen, batch_words=batch.n_words,
-            lr=lr, backend=self.backend, fetch_seconds=fetch_seconds,
-            queue_depth=getattr(self.pipeline, "ready_depth", -1),
-            skipped=skipped)
-        if (self.ckpt_dir and self.ckpt_every
-                and self.state.batches_seen % self.ckpt_every == 0):
-            self.save_checkpoint()
-        if self.on_batch is not None:
-            self.on_batch(self.state)
-        if self.on_metrics is not None:
-            self.on_metrics(metrics)
-        return metrics
+        ahead of training. The call is one ``repro.session.step`` span
+        keyed by the batch's ``(epoch, index)``, the kernel's dispatch a
+        ``repro.session.dispatch`` span inside it."""
+        with tracing.span("repro.session.step",
+                          key=(batch.epoch, batch.index),
+                          words=batch.n_words):
+            lr = self.current_lr()
+            skipped = ((self.state.epoch, self.state.epoch_batch)
+                       in self.poison_skip)
+            if skipped:
+                self.batches_skipped += 1
+                log.warning(
+                    "skipping poison batch (epoch %d, batch %d) — counters "
+                    "advance, tables untouched (%d skipped so far)",
+                    self.state.epoch, self.state.epoch_batch,
+                    self.batches_skipped)
+            elif step is None:
+                step = self._make_step(batch, lr)
+            elif self.placement is not None and not step.has_vocab_shard:
+                # a plain pre-built step carries un-remapped global ids; the
+                # sharded path needs the exchange plan, so rebuild from the
+                # host batch rather than crash (or silently corrupt) below
+                step = self._make_step(batch, lr)
+            if not skipped:
+                with tracing.span("repro.session.dispatch"):
+                    out = ops.step(self._tables(), step, self.cfg,
+                                   backend=self._requested_backend,
+                                   mesh=self.mesh)
+                st = self.state
+                st.w_in, st.w_out = out.w_in, out.w_out
+                st.cold_in, st.cold_out = out.cold_in, out.cold_out
+                st.scale_in, st.scale_out = out.scale_in, out.scale_out
+            self.state.words_seen += batch.n_words
+            self.state.batches_seen += 1
+            self.state.epoch_batch += 1
+            self.fetch_seconds += fetch_seconds
+            metrics = StepMetrics(
+                epoch=self.state.epoch, batches_seen=self.state.batches_seen,
+                words_seen=self.state.words_seen, batch_words=batch.n_words,
+                lr=lr, backend=self.backend, fetch_seconds=fetch_seconds,
+                queue_depth=getattr(self.pipeline, "ready_depth", -1),
+                skipped=skipped)
+            if (self.ckpt_dir and self.ckpt_every
+                    and self.state.batches_seen % self.ckpt_every == 0):
+                self.save_checkpoint()
+            if self.on_batch is not None:
+                self.on_batch(self.state)
+            if self.on_metrics is not None:
+                self.on_metrics(metrics)
+            return metrics
 
     def _prepared(self, batch_iter: Iterator[Batch]
                   ) -> Iterator[tuple]:
@@ -467,14 +481,28 @@ class TrainSession:
         self.last_report = sup.report
         return state
 
-    @property
-    def device_busy_frac(self) -> float:
-        """Fraction of the last ``train()`` wall time NOT spent blocked on
-        the host pipeline — the overlap-efficiency headline: ~host-bound
-        when low, compute-bound (the paper's goal) when near 1."""
-        if not self.wall_seconds:
-            return 0.0
-        return max(0.0, 1.0 - self.fetch_seconds / self.wall_seconds)
+    def host_report(self, n_steps: int) -> Dict[str, float]:
+        """Host numbers of the newest ``n_steps`` trained batches:
+        ``host_wait``, the share of the last ``train()`` spent waiting on
+        the pipeline; from the ``repro.tracing`` rings, ``finalize_ms``,
+        the mean ``repro.pipeline.finalize`` span per batch, and
+        ``neg_draws_per_word``, the ``repro.neg.drawn`` count per real
+        word (NaN where the batches were finalized in worker processes,
+        whose rings stay there)."""
+        steps = tracing.recent("repro.session.step", n_steps)
+        keys = [r.key for r in steps]
+        words = sum(r.attrs["words"] for r in steps)
+        fin = tracing.keyed("repro.pipeline.finalize", keys)
+        drawn = tracing.keyed("repro.neg.drawn", keys)
+        nan = float("nan")
+        return {
+            "host_wait": (self.fetch_seconds / self.wall_seconds
+                          if self.wall_seconds else nan),
+            "finalize_ms": (1e3 * sum(fin.values()) / len(fin)
+                            if fin else nan),
+            "neg_draws_per_word": (sum(drawn.values()) / words
+                                   if drawn and words else nan),
+        }
 
     # -- checkpoint / resume --------------------------------------------------
     def save_checkpoint(self) -> str:

@@ -27,11 +27,13 @@ synchronous pipeline, and what makes mid-epoch resume exact
 from __future__ import annotations
 
 import dataclasses
-import time
+import itertools
+import threading
 from typing import Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.configs.w2v import W2VConfig
 from repro.data.corpus import Corpus
 from repro.data.negatives import NegativeSampler
@@ -260,7 +262,7 @@ class Batch:
     def step_inputs(self, lr) -> "StepInputs":
         """Lift this host batch into the engine API's device-side struct
         (``repro.kernels.registry.StepInputs``), tile plan included."""
-        # local import: keeps this module jax-free until a step is built
+        # local import: the kernels package loads once a step is built
         from repro.kernels.registry import StepInputs
         return StepInputs.from_batch(self, lr)
 
@@ -305,43 +307,50 @@ def finalize_packed(packed: PackedBatch, cfg: W2VConfig,
     sampler table, epoch, placement, bag_table)`` — the keyed rng means any
     worker, in any order, produces the identical Batch, and
     ``plan_exchange`` is rng-free, so the attached exchange inherits the
-    same determinism."""
-    toks, lens = packed.tokens, packed.lengths
-    docs = packed.docs
-    rng = negatives_rng(cfg.seed, epoch, packed.index)
-    if cfg.tile_windows > 1:
-        # tile-shared negatives (Ji et al. HogBatch): one N-set per T
-        # consecutive windows — the dedup win of the tiled kernel
-        negs = sampler.sample_batch_tiled(
-            toks, cfg.negatives, cfg.tile_windows, lens, rng=rng)
-    else:
-        negs = sampler.sample_batch(toks, cfg.negatives, rng=rng)
-    if packed.pad_rows:
-        toks = np.pad(toks, ((0, packed.pad_rows), (0, 0)))
-        negs = np.pad(negs, ((0, packed.pad_rows), (0, 0), (0, 0)))
-        lens = np.pad(lens, (0, packed.pad_rows))
-        if docs is not None:
-            docs = np.pad(docs, (0, packed.pad_rows), constant_values=-1)
-    n_words = int(lens.sum())
-    plan = None
-    if cfg.tile_windows > 1:
-        plan = plan_tiles(toks, negs, lens, cfg.tile_windows)
-    bags = None
-    if bag_table is not None:
-        # (S, L, B) member rows per token position; positions past the
-        # sentence length masked to -1 so sharded request lists only carry
-        # rows the kernel actually touches
-        pos = np.arange(toks.shape[1])[None, :] < lens[:, None]
-        bags = np.where(pos[..., None], bag_table[toks], -1).astype(np.int32)
-    batch = Batch(tokens=toks, negs=negs, lengths=lens, n_words=n_words,
-                  plan=plan, docs=docs, bags=bags,
-                  epoch=epoch, index=packed.index)
-    if placement is not None:
-        # local import: keeps this module free of distributed/ unless a
-        # sharded session actually hands its placement to the pipeline
-        from repro.distributed.vocab_placement import plan_exchange
-        batch.exchange = plan_exchange(batch, placement)
-    return batch
+    same determinism. The work is one ``repro.pipeline.finalize`` span
+    keyed by ``(epoch, packed.index)``, with ``repro.pipeline.negatives``
+    and ``repro.pipeline.plan_tiles`` inside it."""
+    with tracing.span("repro.pipeline.finalize", key=(epoch, packed.index)):
+        toks, lens = packed.tokens, packed.lengths
+        docs = packed.docs
+        rng = negatives_rng(cfg.seed, epoch, packed.index)
+        with tracing.span("repro.pipeline.negatives"):
+            if cfg.tile_windows > 1:
+                # tile-shared negatives (Ji et al. HogBatch): one N-set per
+                # T consecutive windows — the dedup win of the tiled kernel
+                negs = sampler.sample_batch_tiled(
+                    toks, cfg.negatives, cfg.tile_windows, lens, rng=rng)
+            else:
+                negs = sampler.sample_batch(toks, cfg.negatives, rng=rng)
+        if packed.pad_rows:
+            toks = np.pad(toks, ((0, packed.pad_rows), (0, 0)))
+            negs = np.pad(negs, ((0, packed.pad_rows), (0, 0), (0, 0)))
+            lens = np.pad(lens, (0, packed.pad_rows))
+            if docs is not None:
+                docs = np.pad(docs, (0, packed.pad_rows),
+                              constant_values=-1)
+        n_words = int(lens.sum())
+        plan = None
+        if cfg.tile_windows > 1:
+            with tracing.span("repro.pipeline.plan_tiles"):
+                plan = plan_tiles(toks, negs, lens, cfg.tile_windows)
+        bags = None
+        if bag_table is not None:
+            # (S, L, B) member rows per token position; positions past the
+            # sentence length masked to -1 so sharded request lists only
+            # carry rows the kernel actually touches
+            pos = np.arange(toks.shape[1])[None, :] < lens[:, None]
+            bags = np.where(pos[..., None], bag_table[toks],
+                            -1).astype(np.int32)
+        batch = Batch(tokens=toks, negs=negs, lengths=lens, n_words=n_words,
+                      plan=plan, docs=docs, bags=bags,
+                      epoch=epoch, index=packed.index)
+        if placement is not None:
+            # local import: keeps this module free of distributed/ unless a
+            # sharded session actually hands its placement to the pipeline
+            from repro.distributed.vocab_placement import plan_exchange
+            batch.exchange = plan_exchange(batch, placement)
+        return batch
 
 
 class BatchingPipeline:
@@ -450,52 +459,47 @@ class BatchingPipeline:
                 timed: bool = True) -> Iterator[PackedBatch]:
         """Assemble the epoch's encoded stream into indexed (S, L) token
         blocks. Deterministic given (corpus, cfg, epoch) — both pipelines
-        share it, so their batch indexing agrees by construction."""
+        share it, so their batch indexing agrees by construction. The
+        work of each block (encode, subsample and pack) is one
+        ``repro.pipeline.produce`` span keyed by the block's
+        ``(epoch, index)``, closed before the block is yielded."""
         cfg = self.cfg
         L = pad_len or cfg.max_sentence_len
         S = cfg.sentences_per_batch
         with_docs = getattr(self.corpus, "doc_ids", None) is not None
         V = self.vocab.size
-        toks = np.zeros((S, L), np.int32)
-        lens = np.zeros((S,), np.int32)
-        docs = np.full((S,), -1, np.int32)
-        row = 0
-        index = 0
-        stream = self._encoded_stream(epoch)
-        while True:
-            t0 = time.perf_counter()
-            item = next(stream, None)
-            if timed:   # encode+subsample time counts as batching work
-                self.stats.seconds += time.perf_counter() - t0
-            if item is None:
-                break
-            sent, doc = item
-            t0 = time.perf_counter()
-            chunks = [sent[i:i + L] for i in range(0, len(sent), L)]
-            for chunk in chunks:
-                if len(chunk) < 2:
-                    continue
-                toks[row, :len(chunk)] = chunk
-                lens[row] = len(chunk)
-                # doc rows live in table-extra space, past the vocabulary
-                docs[row] = V + doc if doc >= 0 else -1
-                row += 1
-                if row == S:
-                    if timed:
-                        self.stats.seconds += time.perf_counter() - t0
-                    yield PackedBatch(index, toks, lens, 0,
-                                      docs=docs if with_docs else None)
-                    index += 1
-                    toks = np.zeros((S, L), np.int32)
-                    lens = np.zeros((S,), np.int32)
-                    docs = np.full((S,), -1, np.int32)
-                    row = 0
-                    t0 = time.perf_counter()
-            if timed:
-                self.stats.seconds += time.perf_counter() - t0
-        if row:
+
+        def rows() -> Iterator[Tuple[List[int], int]]:
+            """Every row the epoch packs: (chunk, doc table row)."""
+            for sent, doc in self._encoded_stream(epoch):
+                for i in range(0, len(sent), L):
+                    chunk = sent[i:i + L]
+                    if len(chunk) > 1:
+                        # doc rows live in table-extra space, past the
+                        # vocabulary
+                        yield chunk, (V + doc if doc >= 0 else -1)
+
+        it = rows()
+        for index in itertools.count():
+            with tracing.span("repro.pipeline.produce",
+                              key=(epoch, index)) as sp:
+                toks = np.zeros((S, L), np.int32)
+                lens = np.zeros((S,), np.int32)
+                docs = np.full((S,), -1, np.int32)
+                row = 0
+                for chunk, doc in itertools.islice(it, S):
+                    toks[row, :len(chunk)] = chunk
+                    lens[row] = len(chunk)
+                    docs[row] = doc
+                    row += 1
+            if timed:   # encode+subsample+pack time counts as batching work
+                self.stats.seconds += sp.seconds
+            if row == 0:
+                return
             yield PackedBatch(index, toks[:row], lens[:row], S - row,
                               docs=docs[:row] if with_docs else None)
+            if row < S:
+                return
 
     # -- batches ------------------------------------------------------------
     def batches(self, pad_len: Optional[int] = None,
@@ -515,10 +519,11 @@ class BatchingPipeline:
         for packed in self._packed(pad_len, epoch):
             if packed.index < skip_batches:
                 continue
-            t0 = time.perf_counter()
             batch = finalize_packed(packed, self.cfg, self.sampler, epoch,
                                     self.placement, self.bag_table)
-            self.stats.seconds += time.perf_counter() - t0
+            self.stats.seconds += tracing.recent(
+                "repro.pipeline.finalize", 1,
+                thread=threading.get_ident())[0].value
             self.stats.words += batch.n_words
             yield batch
 

@@ -13,6 +13,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro import tracing
+
 
 class AliasTable:
     """Walker alias method over an unnormalized weight vector."""
@@ -44,6 +46,14 @@ class AliasTable:
         return np.where(accept, idx, self.alias[idx])
 
 
+def _count_draws(block: int, rounds: int) -> None:
+    """``repro.neg.drawn``: ids drawn, a whole block every round, padding
+    included; ``repro.neg.rounds``: draws of the block. Both take the key
+    of the enclosing span (the batch being finalized)."""
+    tracing.count("repro.neg.drawn", block * rounds)
+    tracing.count("repro.neg.rounds", rounds)
+
+
 class NegativeSampler:
     def __init__(self, weights: np.ndarray, seed: int = 0):
         self.table = AliasTable(weights)
@@ -67,17 +77,21 @@ class NegativeSampler:
             rng = self.rng
         S, L = targets.shape
         negs = self.table.sample((S, L, n_neg), rng).astype(np.int32)
+        rounds = 1
         for _ in range(16):
             bad = self._conflicts(targets, negs)
             if not bad.any():
-                return negs
+                break
             resampled = self.table.sample(negs.shape, rng).astype(np.int32)
+            rounds += 1
             negs = np.where(bad, resampled, negs)
-        # deterministic fallback: walk ids upward until conflict-free
-        bad = self._conflicts(targets, negs)
-        while bad.any():
-            negs = np.where(bad, (negs + 1) % self.vocab, negs)
+        else:
+            # deterministic fallback: walk ids upward until conflict-free
             bad = self._conflicts(targets, negs)
+            while bad.any():
+                negs = np.where(bad, (negs + 1) % self.vocab, negs)
+                bad = self._conflicts(targets, negs)
+        _count_draws(negs.size, rounds)
         return negs
 
     def sample_batch_tiled(self, targets: np.ndarray, n_neg: int,
@@ -110,13 +124,16 @@ class NegativeSampler:
             tg[np.arange(Lp)[None, :] >= np.asarray(lengths)[:, None]] = -1
         tg = tg.reshape(S, nt, tile)
         negs = self.table.sample((S, nt, n_neg), rng).astype(np.int32)
+        rounds = 1
         for _ in range(16):
             bad = self._tile_conflicts(tg, negs)
             if not bad.any():
                 break
             resampled = self.table.sample(negs.shape,
                                           rng).astype(np.int32)
+            rounds += 1
             negs = np.where(bad, resampled, negs)
+        _count_draws(negs.size, rounds)
         bad = self._tile_conflicts(tg, negs)
         # deterministic fallback: each pass advances every conflicted slot,
         # so `vocab` passes visit every id — if conflicts persist past that,

@@ -56,6 +56,7 @@ from typing import Iterator, List, Optional
 
 log = logging.getLogger("repro.prefetch")
 
+from repro import tracing
 from repro.configs.w2v import W2VConfig
 from repro.data.batching import (Batch, BatchingPipeline, PackedBatch,
                                  finalize_packed)
@@ -124,16 +125,11 @@ class _Pending:
 
 @dataclasses.dataclass
 class PrefetchStats:
-    """Observability for the overlap benchmarks: queue depth over time and
-    the backpressure high-water mark, plus the self-healing counter."""
+    """The backpressure high-water mark and the self-healing counter (the
+    queue depth at each hand-over is on its ``repro.pipeline.handover``
+    span)."""
     max_in_flight: int = 0          # most batches ever past the semaphore
     heals: int = 0                  # worker pools rebuilt after breakage
-    depth_samples: List[int] = dataclasses.field(default_factory=list)
-
-    @property
-    def mean_depth(self) -> float:
-        d = self.depth_samples
-        return sum(d) / len(d) if d else 0.0
 
 
 class AsyncBatchingPipeline(BatchingPipeline):
@@ -306,28 +302,23 @@ class AsyncBatchingPipeline(BatchingPipeline):
         producer.start()
         try:
             while True:
-                try:
-                    item = out.get(timeout=1.0)
-                except queue.Empty:
-                    # bounded poll: a producer that died *between* queue
-                    # puts (OOM-killed, uncaught BaseException path lost)
-                    # must surface as a recoverable fault, not a hang
-                    if not producer.is_alive():
-                        raise PipelineFault(
-                            "producer thread died without delivering "
-                            "end-of-epoch")
-                    continue
+                # the consumer's wait for the next batch, keyed by it,
+                # with the ready depth it leaves behind
+                with tracing.span("repro.pipeline.handover") as sp:
+                    item = self._next_item(out, producer)
+                    if isinstance(item, _Pending):
+                        batch = self._result_healing(item)
+                        with lock:
+                            in_flight[0] -= 1
+                            pending = in_flight[0]
+                        self.ready_depth = self._ready_depth(out)
+                        sp.key = (batch.epoch, batch.index)
+                        sp.attrs["depth"] = self.ready_depth
                 if isinstance(item, _EndOfEpoch):
                     if item.error is not None:
                         raise item.error
                     return
-                batch = self._result_healing(item)
-                with lock:
-                    in_flight[0] -= 1
-                    pending = in_flight[0]
-                self.ready_depth = self._ready_depth(out)
                 slots.release()
-                self.prefetch.depth_samples.append(self.ready_depth)
                 self.stats.words += batch.n_words
                 # steady-state clock (BatchingStats contract): wall time
                 # since the first production activity, minus stretches the
@@ -355,6 +346,23 @@ class AsyncBatchingPipeline(BatchingPipeline):
             producer.join(timeout=10.0)
             # self._executor, not a local: healing may have replaced it
             self._executor.shutdown(wait=True, cancel_futures=True)
+
+    @staticmethod
+    def _next_item(out: "queue.Queue[object]",
+                   producer: threading.Thread) -> object:
+        """The producer's next queue item: a pending batch or the end of
+        the epoch."""
+        while True:
+            try:
+                return out.get(timeout=1.0)
+            except queue.Empty:
+                # bounded poll: a producer that died *between* queue puts
+                # (OOM-killed, uncaught BaseException path lost) must
+                # surface as a recoverable fault, not a hang
+                if not producer.is_alive():
+                    raise PipelineFault(
+                        "producer thread died without delivering "
+                        "end-of-epoch")
 
     @staticmethod
     def _ready_depth(out: "queue.Queue[object]") -> int:

@@ -101,6 +101,7 @@ def run_w2v(args) -> int:
     if trainer.resumed_step is not None:
         print(f"resumed from checkpoint batch {trainer.resumed_step} "
               f"({trainer.state.words_seen:,} words seen)")
+    batches0 = trainer.state.batches_seen
     resilient = (args.max_restarts > 0 or args.step_timeout > 0
                  or args.health_every > 0)
     if resilient:
@@ -119,9 +120,12 @@ def run_w2v(args) -> int:
         trainer.train(max_batches=args.max_batches)
     if args.ckpt_dir:
         print("checkpoint:", trainer.save_checkpoint())
+    host = trainer.host_report(trainer.state.batches_seen - batches0)
     print(f"throughput: {trainer.words_per_sec:,.0f} words/sec "
           f"({trainer.state.words_seen:,} words) "
-          f"device_busy_frac={trainer.device_busy_frac:.3f}")
+          f"host_wait={host['host_wait']:.3f} "
+          f"finalize_ms={host['finalize_ms']:.2f} "
+          f"neg_draws_per_word={host['neg_draws_per_word']:.2f}")
     # bit-exactness witness: identical configs must print identical digests
     # regardless of prefetch_workers (CI's determinism smoke greps this).
     # Covers every table leaf — hot, cold, and int8 scales — so quantized

@@ -24,10 +24,18 @@ step.
 ``close()`` drains the queue before the dispatcher exits: a request
 accepted by :meth:`submit` is always answered (zero dropped queries);
 requests arriving *after* close raise immediately instead of hanging.
+
+Tracing (``repro.tracing``): each request's wait from submit to its
+batch's top-k call is one ``repro.server.queue`` interval keyed by the
+request's id and carrying its batch's id; each batch's co-batching wait,
+top-k call (to the results on the host) and hand-back are the spans
+``repro.server.collect``, ``repro.server.topk`` and
+``repro.server.resolve``, keyed by the batch's id.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import logging
 import queue
 import threading
@@ -36,10 +44,15 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.serve.index import EmbeddingIndex
 from repro.serve.query import make_topk_fn
 
 log = logging.getLogger("repro.serve.server")
+
+# process-wide, so trace keys of different servers never collide
+_REQUEST_IDS = itertools.count()
+_BATCH_IDS = itertools.count()
 
 
 @dataclasses.dataclass
@@ -53,13 +66,16 @@ class QueryResult:
 
 
 class _Request:
-    __slots__ = ("kind", "ids", "k", "t0", "event", "result", "error")
+    __slots__ = ("id", "kind", "ids", "k", "t0_ns", "t0", "event",
+                 "result", "error")
 
     def __init__(self, kind: str, ids: np.ndarray, k: int):
+        self.id = next(_REQUEST_IDS)
         self.kind = kind
         self.ids = ids
         self.k = k
-        self.t0 = time.perf_counter()
+        self.t0_ns = time.perf_counter_ns()
+        self.t0 = self.t0_ns / 1e9          # perf_counter seconds
         self.event = threading.Event()
         self.result: Optional[QueryResult] = None
         self.error: Optional[BaseException] = None
@@ -182,29 +198,32 @@ class EmbeddingServer:
         except queue.Empty:
             return None
 
-    def _collect_batch(self) -> Optional[List[_Request]]:
+    def _collect_batch(self) -> Optional[Tuple[int, List[_Request]]]:
         """Block for a first request, then co-batch same-kind arrivals
-        until the row budget or the deadline runs out."""
+        until the row budget or the deadline runs out. Returns the new
+        batch's id and its requests."""
         first = self._take_first()
         if first is None:
             return None
-        batch, rows = [first], first.ids.shape[0]
-        deadline = first.t0 + self.deadline_s
-        while rows < self.batch_size:
-            remaining = deadline - time.perf_counter()
-            if remaining <= 0:
-                break
-            try:
-                nxt = self._queue.get(timeout=remaining)
-            except queue.Empty:
-                break
-            if (nxt.kind != first.kind
-                    or rows + nxt.ids.shape[0] > self.batch_size):
-                self._carry = nxt          # rides the next batch
-                break
-            batch.append(nxt)
-            rows += nxt.ids.shape[0]
-        return batch
+        with tracing.span("repro.server.collect",
+                          key=next(_BATCH_IDS)) as sp:
+            batch, rows = [first], first.ids.shape[0]
+            deadline = first.t0 + self.deadline_s
+            while rows < self.batch_size:
+                remaining = deadline - time.perf_counter()
+                if remaining <= 0:
+                    break
+                try:
+                    nxt = self._queue.get(timeout=remaining)
+                except queue.Empty:
+                    break
+                if (nxt.kind != first.kind
+                        or rows + nxt.ids.shape[0] > self.batch_size):
+                    self._carry = nxt          # rides the next batch
+                    break
+                batch.append(nxt)
+                rows += nxt.ids.shape[0]
+        return sp.key, batch
 
     def _fn_for(self, index: EmbeddingIndex, mode: str):
         key = (index.placement, mode, self.k, self.batch_size)
@@ -215,7 +234,7 @@ class EmbeddingServer:
             self._fns[key] = fn
         return fn
 
-    def _serve_batch(self, batch: List[_Request]) -> None:
+    def _serve_batch(self, batch_id: int, batch: List[_Request]) -> None:
         index = self.current_index()       # ONE snapshot for the batch
         kind = batch[0].kind
         ids = np.concatenate([r.ids for r in batch], axis=0)
@@ -225,33 +244,40 @@ class EmbeddingServer:
             fill = np.zeros((pad,) + ids.shape[1:], np.int32)
             ids = np.concatenate([ids, fill], axis=0)
         fn = self._fn_for(index, kind)
-        out_ids, out_scores = fn(index.hot, index.cold, ids)
-        out_ids = np.asarray(out_ids)[:n]
-        out_scores = np.asarray(out_scores)[:n]
+        with tracing.span("repro.server.topk", key=batch_id,
+                          requests=len(batch), rows=n) as sp:
+            out_ids, out_scores = fn(index.hot, index.cold, ids)
+            out_ids = np.asarray(out_ids)[:n]
+            out_scores = np.asarray(out_scores)[:n]
         now = time.perf_counter()
-        self.batches += 1
-        off = 0
         for r in batch:
-            m = r.ids.shape[0]
-            lat = (now - r.t0) * 1e6
-            r.resolve(QueryResult(
-                ids=out_ids[off:off + m, :r.k],
-                scores=out_scores[off:off + m, :r.k],
-                snapshot_step=index.step, latency_us=lat))
-            off += m
-            self.served += m
-            self.latencies_us.append(lat)
+            tracing.interval("repro.server.queue", r.t0_ns, sp.start_ns,
+                             key=r.id, batch=batch_id)
+        self.batches += 1
+        with tracing.span("repro.server.resolve", key=batch_id):
+            off = 0
+            for r in batch:
+                m = r.ids.shape[0]
+                lat = (now - r.t0) * 1e6
+                r.resolve(QueryResult(
+                    ids=out_ids[off:off + m, :r.k],
+                    scores=out_scores[off:off + m, :r.k],
+                    snapshot_step=index.step, latency_us=lat))
+                off += m
+                self.served += m
+                self.latencies_us.append(lat)
 
     def _dispatch_loop(self) -> None:
         while True:
-            batch = self._collect_batch()
-            if batch is None:
+            got = self._collect_batch()
+            if got is None:
                 if self._closed and self._carry is None \
                         and self._queue.empty():
                     return                 # drained: safe to exit
                 continue
+            batch_id, batch = got
             try:
-                self._serve_batch(batch)
+                self._serve_batch(batch_id, batch)
             except BaseException as e:  # noqa: BLE001 — fail the batch,
                 for r in batch:             # never strand its futures
                     r.fail(e)

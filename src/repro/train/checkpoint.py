@@ -287,13 +287,15 @@ def latest_step(ckpt_dir: str) -> Optional[int]:
     while steps:
         step = steps.pop()
         d = os.path.join(ckpt_dir, f"step_{step:08d}")
-        try:
-            with open(os.path.join(d, "manifest.json")) as f:
-                json.load(f)
-            ok = os.path.exists(os.path.join(d, "arrays.npz"))
-        except (OSError, ValueError):
-            ok = False
-        if ok:
+        if _complete(d):
+            return step
+        # a same-step re-save displaces the dir by rename just before its
+        # replacement lands, complete: a dir missing at the check was
+        # displaced (or pruned), not left partial, and is looked at once
+        # more before anything is quarantined
+        if not os.path.isdir(d):
+            continue
+        if _complete(d):
             return step
         log.warning("checkpoint step %d is partial — quarantining and "
                     "falling back", step)
@@ -304,6 +306,17 @@ def latest_step(ckpt_dir: str) -> Optional[int]:
             # our check and the rename — nothing left to quarantine
             pass
     return None
+
+
+def _complete(d: str) -> bool:
+    """The light completeness check: a parseable manifest and an arrays
+    file."""
+    try:
+        with open(os.path.join(d, "manifest.json")) as f:
+            json.load(f)
+        return os.path.exists(os.path.join(d, "arrays.npz"))
+    except (OSError, ValueError):
+        return False
 
 
 def _read_manifest(d: str) -> Dict:

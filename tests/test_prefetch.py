@@ -2,11 +2,13 @@
 bit-identity with the synchronous pipeline, bounded-queue backpressure,
 clean shutdown, steady-state stats, and exact mid-epoch resume with
 prefetch enabled."""
+import threading
 import time
 
 import numpy as np
 import pytest
 
+from repro import tracing
 from repro.configs.w2v import smoke
 from repro.data.batching import BatchingPipeline
 from repro.data.corpus import synthetic_zipf_corpus
@@ -121,7 +123,12 @@ def test_backpressure_bounds_in_flight_batches():
         n += 1
     assert n >= 6
     assert 1 <= apipe.prefetch.max_in_flight <= 2
-    assert len(apipe.prefetch.depth_samples) == n
+    # one hand-over span per batch, keyed by it, carrying the ready depth
+    hand = [r for r in tracing.recent("repro.pipeline.handover",
+                                      thread=threading.get_ident())
+            if "depth" in r.attrs][-n:]
+    assert [r.key for r in hand] == [(0, i) for i in range(n)]
+    assert all(0 <= r.attrs["depth"] <= 2 for r in hand)
 
 
 def test_worker_exception_propagates_and_shuts_down(monkeypatch):
