@@ -10,16 +10,27 @@ The sharded path runs under ``shard_map`` on the mesh ``data`` axis
    O(distinct·d).
 2. **Partial top-k** — each shard scores the candidates it is
    responsible for (shard 0 additionally scores the replicated hot head,
-   so no candidate is scored twice) and takes a local
-   ``jax.lax.top_k``.
+   so no candidate is scored twice) and takes a local top-k by a
+   block-max prefilter (:func:`_block_rank`): the ``C`` candidates are
+   cut into blocks of ``g``, the ``k`` blocks with the best maxima are
+   picked, and only their ``k·g`` scores are ranked. This is exact: a
+   shard's candidate ids ascend with their index, so blocks are in id
+   order, and a block's best element ranks ahead of every other in it.
+   If a top-k element's block were not among the ``k`` best blocks,
+   those ``k`` blocks would each hold an element ranked ahead of it, so
+   it would not be in the top-k. ``g`` is a multiple of 128 near
+   ``sqrt(C / k)``, which balances the ``C / g`` block maxima ranked
+   against the ``k·g`` candidates; where ``k·g >= C`` the shard ranks
+   all ``C`` (:func:`_block_size`).
 3. **Cross-shard merge** — the ``n·k`` per-shard partials are
-   ``all_gather``'d and re-ranked by ``(score desc, id asc)``; ties
-   break identically to the dense oracle, which ranks with the same
-   lexicographic key.
+   ``all_gather``'d and re-ranked by ``(score desc, id asc)`` with the
+   full :func:`_rank`; ties break identically to the dense oracle, which
+   ranks with the same lexicographic key.
 
 :func:`dense_topk` is the single-host jnp oracle: the same math on the
-merged ``(V, d)`` table, kept as the parity reference the tests and the
-serve-smoke CI job compare against (ids identical, scores within 1e-6).
+merged ``(V, d)`` table, ranked by the full :func:`_rank` with no
+prefilter, kept as the parity reference the tests and the serve-smoke
+CI job compare against (ids identical, scores within 1e-6).
 
 Query encodings (ids are global vocabulary ids):
 
@@ -31,7 +42,8 @@ Query encodings (ids are global vocabulary ids):
 """
 from __future__ import annotations
 
-from typing import Callable, Tuple
+import math
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +61,44 @@ def _rank(scores: jax.Array, ids: jax.Array, k: int
     order = jnp.lexsort((ids, -scores), axis=-1)[..., :k]
     return (jnp.take_along_axis(ids, order, axis=-1),
             jnp.take_along_axis(scores, order, axis=-1))
+
+
+def _block_size(c: int, k: int) -> Optional[int]:
+    """The prefilter's block length for ``c`` candidates and top ``k``:
+    a multiple of 128 near ``sqrt(c / k)``, or None where its ``k``
+    blocks would hold every candidate (rank all ``c`` instead)."""
+    g = 128 * max(1, round(math.sqrt(c / k) / 128))
+    return g if k * g < c else None
+
+
+def _block_rank(scores: jax.Array, ids: jax.Array, k: int, g: int
+                ) -> Tuple[jax.Array, jax.Array]:
+    """:func:`_rank` of ``(B, C)`` scores whose ids ``(C,)`` ascend along
+    the candidate axis, ranking only the ``k`` blocks of ``g`` with the
+    best maxima (exact: see the module docstring). Requires
+    ``k·g <= C``."""
+    b, c = scores.shape
+    nb = -(-c // g)
+    # the last block's padding (-inf) ranks after every candidate
+    padded = jnp.pad(scores, ((0, 0), (0, nb * g - c)),
+                     constant_values=NEG_INF)
+    best = padded.reshape(b, nb, g).max(axis=-1)          # (B, nb)
+    blocks, _ = _rank(best, jnp.broadcast_to(
+        jnp.arange(nb, dtype=jnp.int32), best.shape), k)  # (B, k)
+    # slice each chosen block from the unpadded scores; the last block's
+    # slice is clamped back to end at c, so mask what it re-reads of the
+    # block before it
+    first = blocks * g
+    start = jnp.minimum(first, c - g)
+    sc = jax.vmap(jax.vmap(
+        lambda row, s0: jax.lax.dynamic_slice(row, (s0,), (g,)),
+        in_axes=(None, 0)))(scores, start)                # (B, k, g)
+    gid = jax.vmap(jax.vmap(
+        lambda s0: jax.lax.dynamic_slice(ids, (s0,), (g,))))(start)
+    fresh = start[..., None] + jnp.arange(g) >= first[..., None]
+    sc = jnp.where(fresh, sc, NEG_INF).reshape(b, k * g)
+    gid = jnp.where(fresh, gid, jnp.iinfo(jnp.int32).max).reshape(b, k * g)
+    return _rank(sc, gid, k)
 
 
 def _query_vectors(hot: jax.Array, cold: jax.Array, flat_ids: jax.Array,
@@ -86,6 +136,8 @@ def make_topk_fn(placement: VocabPlacement, mesh, mode: str = "nn",
     scores)``, both ``(B, k)``. ``ids`` is ``(B,)`` for ``mode="nn"``,
     ``(B, 3)`` for ``mode="analogy"``; out-of-range/padded query slots
     are tolerated (clipped gathers) — callers mask their results.
+    ``fn.ranked`` is the number of candidates a shard's final sort ranks
+    per query row: ``k·g`` under the prefilter, else all of its own.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -97,6 +149,7 @@ def make_topk_fn(placement: VocabPlacement, mesh, mode: str = "nn",
             f"(hot={hot_n} + cold_per_shard={cps})")
     if mode not in ("nn", "analogy"):
         raise ValueError(f"unknown query mode {mode!r} (nn | analogy)")
+    g = _block_size(hot_n + cps, k)
 
     def local(hot, cold, ids):
         s = jax.lax.axis_index("data")
@@ -115,7 +168,11 @@ def make_topk_fn(placement: VocabPlacement, mesh, mode: str = "nn",
         dead |= (jnp.arange(hot_n + cps) < hot_n)[None, :] & (s != 0)
         dead |= (gids[None, None, :] == excl[:, :, None]).any(axis=1)
         scores = jnp.where(dead, NEG_INF, scores)
-        ids_l, sc_l = _rank(scores, jnp.broadcast_to(gids, scores.shape), k)
+        if g is None:
+            ids_l, sc_l = _rank(
+                scores, jnp.broadcast_to(gids, scores.shape), k)
+        else:                           # gids ascend: blocks in id order
+            ids_l, sc_l = _block_rank(scores, gids, k, g)
         # cross-shard merge: n·k partials, re-ranked by the same rule
         g_sc = jax.lax.all_gather(sc_l, "data")           # (n, B, k)
         g_id = jax.lax.all_gather(ids_l, "data")
@@ -129,7 +186,9 @@ def make_topk_fn(placement: VocabPlacement, mesh, mode: str = "nn",
         out_specs=(P(), P()),
         check_vma=False,
     )
-    return jax.jit(sharded)
+    fn = jax.jit(sharded)
+    fn.ranked = hot_n + cps if g is None else k * g
+    return fn
 
 
 def dense_topk(emb: np.ndarray, ids: np.ndarray, k: int = 5,

@@ -30,7 +30,9 @@ batch's top-k call is one ``repro.server.queue`` interval keyed by the
 request's id and carrying its batch's id; each batch's co-batching wait,
 top-k call (to the results on the host) and hand-back are the spans
 ``repro.server.collect``, ``repro.server.topk`` and
-``repro.server.resolve``, keyed by the batch's id.
+``repro.server.resolve``, keyed by the batch's id. The top-k span
+carries ``ranked``, the candidates a shard's final sort ranks per query
+row (``make_topk_fn``'s ``fn.ranked``; None for a top-k without it).
 """
 from __future__ import annotations
 
@@ -245,7 +247,8 @@ class EmbeddingServer:
             ids = np.concatenate([ids, fill], axis=0)
         fn = self._fn_for(index, kind)
         with tracing.span("repro.server.topk", key=batch_id,
-                          requests=len(batch), rows=n) as sp:
+                          requests=len(batch), rows=n,
+                          ranked=getattr(fn, "ranked", None)) as sp:
             out_ids, out_scores = fn(index.hot, index.cold, ids)
             out_ids = np.asarray(out_ids)[:n]
             out_scores = np.asarray(out_scores)[:n]

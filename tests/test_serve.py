@@ -10,6 +10,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from repro.distributed.vocab_placement import VocabPlacement
@@ -17,6 +18,7 @@ from repro.serve import (EmbeddingIndex, EmbeddingServer, SnapshotWatcher,
                          dense_topk, make_topk_fn)
 from repro.serve.chaos import SCHEDULES, _publish, run_serve_chaos
 from repro.serve.index import _restripe
+from repro.serve.query import _block_rank, _rank
 from repro.train import checkpoint as ckpt
 
 V, HOT, D = 64, 12, 16
@@ -222,6 +224,129 @@ def test_topk_parity_multishard(subproc, n_shards):
         print("MULTISHARD_PARITY_OK")
     """, n_devices=n_shards)
     assert "MULTISHARD_PARITY_OK" in r.stdout, r.stdout + r.stderr
+
+
+# -- block-max prefilter ------------------------------------------------------
+# (candidates C, k, block g, distinct score values, share of -inf scores)
+_PREFILTER_CASES = {
+    "few_values_maxima_tie": (300, 4, 8, 3, 0.0),
+    "more_dead_than_c_minus_k": (96, 6, 8, 4, 0.97),
+    "c_not_multiple_of_g": (101, 3, 8, 50, 0.2),
+    "kg_just_below_c": (41, 5, 8, 6, 0.1),
+    "kg_at_c": (40, 5, 8, 6, 0.1),
+    "k1": (200, 1, 32, 5, 0.3),
+    "all_dead": (64, 3, 4, 2, 1.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PREFILTER_CASES))
+def test_block_rank_matches_full_rank(case):
+    """The prefilter ranks exactly as the full ``(score desc, id asc)``
+    sort, ids and scores, on tie-heavy rows with a forced small block."""
+    c, k, g, values, dead = _PREFILTER_CASES[case]
+    for seed in range(4):
+        rng = np.random.default_rng(seed)
+        scores = rng.integers(values, size=(6, c)).astype(np.float32)
+        scores[rng.random((6, c)) < dead] = -np.inf
+        # ascending ids with gaps, as a shard's strided cold ids are
+        ids = np.sort(rng.choice(10 * c, size=c, replace=False)).astype(
+            np.int32)
+        want = _rank(jnp.asarray(scores),
+                     jnp.broadcast_to(ids, scores.shape), k)
+        got = _block_rank(jnp.asarray(scores), jnp.asarray(ids), k, g)
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+        np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
+PV, PHOT = 4000, 100      # C = 4,000 candidates: k=5 prefilters by g=128
+
+
+def _prefilter_index():
+    """A table whose duplicate rows straddle the block boundaries at
+    candidate 128 and 256 (one shard: candidate index == id)."""
+    table = _table(8, PV)
+    table[124:132] = table[5] + 0.05 * table[9]        # 8 tied, across 128
+    table[254:258] = table[300] + 0.05 * table[11]     # 4 tied, across 256
+    placement = VocabPlacement(vocab_size=PV, hot=PHOT, n_shards=1)
+    h, c = placement.split(table)
+    return EmbeddingIndex._stage(placement, h, c, _mesh1())
+
+
+@pytest.mark.parametrize("mode", ["nn", "analogy"])
+def test_topk_prefilter_parity_with_dense(mode):
+    idx = _prefilter_index()
+    rng = np.random.default_rng(1)
+    if mode == "nn":
+        ids = np.concatenate([[5, 300, 124, 131, 256, PHOT - 1, PHOT],
+                              rng.integers(PV, size=9)]).astype(np.int32)
+    else:
+        ids = np.concatenate([[[5, 9, 300], [300, 11, 5], [127, 3, 128]],
+                              rng.integers(PV, size=(5, 3))]).astype(np.int32)
+    fn = make_topk_fn(idx.placement, idx.mesh, mode=mode, k=5)
+    assert fn.ranked == 5 * 128
+    got_ids, got_sc = fn(idx.hot, idx.cold, ids)
+    want_ids, want_sc = dense_topk(idx.dense_embeddings(), ids, k=5,
+                                   mode=mode)
+    np.testing.assert_array_equal(np.asarray(got_ids), want_ids)
+    np.testing.assert_allclose(np.asarray(got_sc), want_sc, atol=1e-6)
+    if mode == "nn":      # the ties resolve by id across the boundary
+        np.testing.assert_array_equal(want_ids[0], np.arange(124, 129))
+        np.testing.assert_array_equal(want_ids[1][:4], np.arange(254, 258))
+
+
+@pytest.mark.parametrize("v,k,ranked", [(PV, 5, 5 * 128), (PV, 1, 128),
+                                        (V, 3, V), (PV, 40, PV)])
+def test_topk_ranked_counts_the_final_sort(v, k, ranked):
+    """``fn.ranked``: k·g where the prefilter engages, all C candidates
+    where k blocks would hold them all."""
+    placement = VocabPlacement(vocab_size=v, hot=12, n_shards=1)
+    assert make_topk_fn(placement, _mesh1(), k=k).ranked == ranked
+
+
+@pytest.mark.parametrize("v,full_sort", [(PV, False), (V, True)])
+def test_topk_prefilter_sorts_no_full_candidate_row(v, full_sort):
+    """The compiled top-k sorts a (B, C)-wide operand only where the
+    shape rule falls back to the full rank."""
+    import re
+
+    idx = _prefilter_index() if v == PV else _index()
+    fn = make_topk_fn(idx.placement, idx.mesh, mode="nn", k=5)
+    text = fn.lower(idx.hot, idx.cold, np.zeros(8, np.int32)).compile(
+        ).as_text()
+    widths = {int(w) for line in text.splitlines() if " sort(" in line
+              for w in re.findall(r"\[8,(\d+)\]", line)}
+    assert widths, "no sort found"
+    assert (v in widths) == full_sort, widths
+
+
+def test_topk_prefilter_parity_multishard(subproc):
+    """Each shard prefilters its own strided candidates; the merge of the
+    shards' partials stays exact."""
+    r = subproc(f"""
+        import numpy as np, jax
+        from jax.sharding import Mesh
+        from repro.distributed.vocab_placement import VocabPlacement
+        from repro.serve.index import EmbeddingIndex
+        from repro.serve.query import dense_topk, make_topk_fn
+        mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+        rng = np.random.default_rng(0)
+        v, hot = {PV}, {PHOT}
+        table = rng.standard_normal((v, 8)).astype(np.float32)
+        table[250:262] = table[7]          # ties on both shards
+        pl = VocabPlacement(vocab_size=v, hot=hot, n_shards=2)
+        h, c = pl.split(table)
+        idx = EmbeddingIndex._stage(pl, h, c, mesh)
+        ids = np.concatenate([[7, 250, hot - 1, hot, v - 1],
+                              rng.integers(v, size=11)]).astype(np.int32)
+        fn = make_topk_fn(pl, mesh, mode="nn", k=5)
+        assert fn.ranked == 5 * 128 < hot + pl.cold_per_shard
+        gi, gs = fn(idx.hot, idx.cold, ids)
+        wi, ws = dense_topk(idx.dense_embeddings(), ids, k=5)
+        assert np.array_equal(np.asarray(gi), wi), (gi, wi)
+        assert np.allclose(np.asarray(gs), ws, atol=1e-6)
+        print("PREFILTER_MULTISHARD_OK")
+    """, n_devices=2)
+    assert "PREFILTER_MULTISHARD_OK" in r.stdout, r.stdout + r.stderr
 
 
 # -- session accessors --------------------------------------------------------

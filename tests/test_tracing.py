@@ -173,6 +173,8 @@ def test_server_records_one_queue_interval_per_request(fresh):
     batch_ids = {r.key for r in topk}
     assert len(batch_ids) == n_batches
     assert sum(r.attrs["requests"] for r in topk) == 10
+    # a 64-row table: the top-k ranks all its candidates
+    assert {r.attrs["ranked"] for r in topk} == {64}
     queued = {r.key: r for r in tracing.recent("repro.server.queue")
               if r.key in {h.id for h in handles}}
     assert len(queued) == 10
