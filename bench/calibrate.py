@@ -13,15 +13,17 @@
 readings). On the control seeds it prints those of the control and of
 the step's faults, each in the program's place: the reference one
 precision step down (three bf16 passes), half of every batch left out,
-and one token of every batch altered. On the fault seeds it plants each
-of the host pipeline's faults in the program (``harness.faults``)
-and prints the pipeline's numbers over as many batches as set-up hands
-over, drawn from the pipeline alone. ``serve`` runs short windows at the
-cell's rate and prints the program's numbers, and on the control seeds those of int8-
-and fp8-scored dense top-k in the server's place. ``sweep`` runs the
-serving cell at each rate and prints the latency percentiles and how
-the backlog grew, to find the highest sustained rate. Each reading is
-one JSON line. The benchmark's own runs never run this.
+and one token of every batch altered, over the rows that either side's
+batches touch. On the fault seeds it plants each of the host pipeline's
+faults in the program (``harness.faults``) and prints the pipeline's
+numbers over as many batches as set-up hands over, drawn from the
+pipeline alone, and plants each step fault (a write outside the checked
+rows) and prints every number of the check. ``serve`` runs short windows
+at the cell's rate and prints the program's numbers, and on the control
+seeds those of int8- and fp8-scored dense top-k in the server's place.
+``sweep`` runs the serving cell at each rate and prints the latency
+percentiles and how the backlog grew, to find the highest sustained
+rate. Each reading is one JSON line. The benchmark's own runs never run this.
 """
 import argparse
 import json
@@ -44,38 +46,41 @@ def _seeds(text: str):
     return [int(s) for s in text.split(",") if s]
 
 
-def train(cell, seeds, control_seeds, fault_seeds, fault_names) -> None:
+def train(cell, seeds, control_seeds, fault_seeds, fault_names,
+          devices) -> None:
+    import jax
     import numpy as np
 
-    from harness import pipeline, reference, train as drv, zipf
+    from harness import pipeline, reference, rows, train as drv, zipf
 
-    stream = cell.traffic["layout"] == "stream"
     t_sub = float(cell.config["w2v"]["subsample_t"])
 
-    def pipeline_numbers(st, seed):
-        return pipeline.check(st.pipe.handed, st.corpus, stream, st.counts,
-                              t_sub, zipf.rng_for(seed, zipf.NEGATIVE_TAG))
+    def pipeline_numbers(handed, data, seed):
+        return pipeline.check(handed, data.sentences, data.stream,
+                              data.counts, t_sub,
+                              zipf.rng_for(seed, zipf.NEGATIVE_TAG))
+
+    def started(seed):
+        st = drv.start(cell.config, cell.traffic, seed, devices)
+        st.stream.close()
+        st.sess = st.stream = None
+        return st
 
     for seed in seeds:
         t = time.perf_counter()
-        st = drv.start(cell.config, cell.traffic, seed)
-        st.stream.close()
-        st.sess = st.stream = None
+        st = started(seed)
         prog_s = time.perf_counter() - t
         t = time.perf_counter()
-        v, cfg = int(cell.config["vocab_size"]), st.cfg
-        lrs = [m.lr for m in st.warm]
         kept = st.kept()
-        ref = drv.reference_states(cfg.seed, v, cfg.dim, cfg.fixed_window,
-                                   kept, lrs)
-        numbers = drv.compare(st.prog, ref, kept, cfg.fixed_window)
-        numbers.update(pipeline_numbers(st, seed))
+        numbers = drv.check(st, cell.config, cell.traffic, devices)
         emit(cell=cell.name, seed=seed, side="program", numbers=numbers,
              start_s=prog_s, check_s=time.perf_counter() - t,
              words=[b.words for b in st.pipe.handed])
         if seed not in control_seeds:
             continue
-        st.prog = None
+        cfg, v, w_f = st.cfg, st.data.vocab, st.cfg.fixed_window
+        lrs = [m.lr for m in st.warm[:drv.CHECK_STEPS]]
+        st = None
         half = [(t_, n, np.where(np.arange(len(ln)) % 2 == 1, 0, ln))
                 for t_, n, ln in kept]
         altered = []
@@ -83,29 +88,46 @@ def train(cell, seeds, control_seeds, fault_seeds, fault_names) -> None:
             t_ = t_.copy()
             t_[0, 1] = (t_[0, 1] + 1) % v
             altered.append((t_, n, ln))
+        # the reference against itself: over the rows either side touches
+        ids = rows.union(rows.batch_ids(*b) for b in kept + altered)
+        begin = rows.Initial(jax.random.PRNGKey(cfg.seed), v, cfg.dim,
+                             devices).rows(ids)
+
+        def local(batches):
+            return [(rows.positions(ids, t_), rows.positions(ids, n), ln)
+                    for t_, n, ln in batches]
+
+        ref = drv.reference_states(begin, local(kept), lrs, w_f,
+                                   devices=devices)
         sides = {"control_bf16x3": (kept, reference.mm_bf16x3),
                  "fault_half_batch": (half, reference.mm_f32),
                  "fault_token_altered": (altered, reference.mm_f32)}
         for side, (batches, mm) in sides.items():
-            got = drv.reference_states(cfg.seed, v, cfg.dim,
-                                       cfg.fixed_window, batches, lrs, mm)
+            got = drv.reference_states(begin, local(batches), lrs, w_f, mm,
+                                       devices)
             emit(cell=cell.name, seed=seed, side=side,
-                 numbers=drv.compare(got, ref, kept, cfg.fixed_window))
+                 numbers=drv.compare(got, ref, local(kept), w_f))
             del got
     for seed in fault_seeds:
         for side in fault_names:
+            if side in faults.STEP:
+                with faults.STEP[side]():
+                    st = started(seed)
+                emit(cell=cell.name, seed=seed, side=side,
+                     numbers=drv.check(st, cell.config, cell.traffic,
+                                       devices))
+                del st
+                continue
             with faults.PIPELINE[side]():
-                sess, pipe, cfg, corpus, counts = drv.make_session(
-                    cell.config, cell.traffic, seed)
+                sess, pipe, cfg, data = drv.make_session(
+                    cell.config, cell.traffic, seed, devices)
                 it = pipe.batches(pad_len=cfg.resolved_pad_len, epoch=0)
                 for _ in range(drv.CHECK_STEPS + 1):
                     next(it)
                 it.close()
-            st = drv.Started(None, pipe, cfg, None, None, None, seed, corpus,
-                             counts)
             emit(cell=cell.name, seed=seed, side=side,
-                 numbers=pipeline_numbers(st, seed))
-            del sess, st
+                 numbers=pipeline_numbers(pipe.handed, data, seed))
+            del sess
 
 
 class _Answer:
@@ -181,7 +203,7 @@ def main() -> int:
         train(cell, _seeds(args.seeds), set(_seeds(args.control_seeds)),
               _seeds(args.fault_seeds),
               [f for f in args.faults.split(",") if f] or sorted(
-                  faults.PIPELINE))
+                  faults.PIPELINE) + sorted(faults.STEP), devices)
     elif args.mode == "serve":
         serve(cell, _seeds(args.seeds), set(_seeds(args.control_seeds)),
               args.seconds, devices)

@@ -85,12 +85,16 @@ def mm_int8(a, b):
 # Training
 # ---------------------------------------------------------------------------
 
+def input_rows(key, vocab: int, dim: int):
+    """word2vec's initial input rows, ~ U(-0.5/d, 0.5/d)."""
+    return (jax.random.uniform(key, (vocab, dim), jnp.float32) - 0.5) / dim
+
+
 @functools.partial(jax.jit, static_argnums=(1, 2))
 def init_tables(key, vocab: int, dim: int):
-    """word2vec's initialisation: input rows ~ U(-0.5/d, 0.5/d), output
+    """word2vec's initialisation: input rows :func:`input_rows`, output
     rows zero."""
-    w_in = (jax.random.uniform(key, (vocab, dim), jnp.float32) - 0.5) / dim
-    return w_in, jnp.zeros((vocab, dim), jnp.float32)
+    return input_rows(key, vocab, dim), jnp.zeros((vocab, dim), jnp.float32)
 
 
 def sigmoid(x):
@@ -165,6 +169,30 @@ def sgns_step(w_in, w_out, tokens, negs, lengths, lr, w_f: int,
     (w_in, w_out), _ = jax.lax.scan(body, (w_in, w_out),
                                     (tokens, negs, lengths))
     return w_in, w_out
+
+
+def replicated_step(w_in, w_out, tokens, negs, lengths, lr, w_f: int,
+                    mm: Callable = mm_f32, devices=None):
+    """One batch trained data parallel (Hogwild averaging), a replica on
+    each of ``devices``: the batch's rows split into as many equal
+    contiguous blocks, each trained by :func:`sgns_step` from the same
+    tables on its own device, and the replicas' tables summed in replica
+    order on the first device and divided by their number. A device of
+    its own each keeps one replica's tables, not all of them, beside the
+    sum. With one device (or none named) it is :func:`sgns_step`."""
+    if devices is None or len(devices) == 1:
+        return sgns_step(w_in, w_out, tokens, negs, lengths, lr, w_f, mm)
+    n = len(devices)
+    per = tokens.shape[0] // n
+    outs = [sgns_step(*jax.device_put(
+        (w_in, w_out, tokens[r * per:(r + 1) * per],
+         negs[r * per:(r + 1) * per], lengths[r * per:(r + 1) * per], lr),
+        dev, may_alias=False), w_f, mm) for r, dev in enumerate(devices)]
+    total = outs.pop(0)
+    while outs:
+        total = tuple(a + b for a, b in zip(
+            total, jax.device_put(outs.pop(0), devices[0])))
+    return tuple(t / n for t in total)
 
 
 def unpad(tokens: np.ndarray, negs: np.ndarray, lengths: np.ndarray,

@@ -28,6 +28,8 @@ sys.path.insert(1, os.path.join(os.path.dirname(HERE), "src"))
 
 from harness import checks, device, spec, trace  # noqa: E402
 
+# a traffic kind's runner: these two by their old names, any other kind
+# by the module ``harness.<kind>``
 RUNNERS = {"train": "harness.train", "serve_open_loop": "harness.serve"}
 
 
@@ -66,7 +68,8 @@ def main(argv=None) -> int:
             f"({e})")
         return 2
     import importlib
-    runner = importlib.import_module(RUNNERS[cell.traffic["kind"]])
+    kind = cell.traffic["kind"]
+    runner = importlib.import_module(RUNNERS.get(kind, "harness." + kind))
     log(f"bench: {cell.name} seed {args.seed} on {devices[0].device_kind} "
         f"x{len(devices)}, compile cache {device.CACHE_DIR}")
     with tempfile.TemporaryDirectory(prefix="bench-trace-") as tdir:

@@ -63,6 +63,16 @@ def test_broken_host_pipeline_is_not_correct(train_cell, fault):
     assert not correct(train_cell, out), out["numbers"]
 
 
+@pytest.mark.parametrize("fault", sorted(faults.STEP))
+def test_write_outside_the_checked_rows_is_not_correct(train_cell, fault):
+    """A step that writes a row no checked batch touches: only
+    ``untouched_changed`` sees it."""
+    with faults.STEP[fault]():
+        out = tiny.run(train_cell)
+    assert out["numbers"]["untouched_changed"] >= 1
+    assert not correct(train_cell, out), out["numbers"]
+
+
 def test_sound_serving_run_is_correct():
     cell = tiny.serve_cell()
     out = tiny.run(cell)

@@ -1,7 +1,9 @@
 """``run.py`` end to end on the CPU, its look for a chip skipped: the
 result line has the contract's keys, in order, with the cell's metrics."""
+import dataclasses
 import json
 
+import harness
 import jax
 import pytest
 
@@ -58,3 +60,28 @@ def test_traced_result_line(cpu_run, capsys, monkeypatch):
     assert out["device"]["busy_s"] == 0.5
     assert out["breakdown"]["idle_gaps"] == [["bench.fetch", 0.5]]
     assert list(out)[-1] == "checks"
+
+
+KIND = '''
+def run(cell, seed, seconds, t_process, devices, trace_dir=None, log=print):
+    return {"setup_s": 1.0, "e2e": {"words_per_s": 2.0}, "attempted": 3,
+            "failed": 0, "numbers": {"order_breaks": 0.0}, "rec": {},
+            "memory_peak_bytes": 4}
+'''
+
+
+def test_traffic_kind_found_by_module(cpu_run, capsys, monkeypatch,
+                                      tmp_path):
+    """A kind that ``RUNNERS`` does not list runs ``harness.<kind>``."""
+    (tmp_path / "kind_test.py").write_text(KIND)
+    monkeypatch.setattr(harness, "__path__",
+                        list(harness.__path__) + [str(tmp_path)])
+    cell = tiny.train_cell("w2v-text8.stream")
+    cell = dataclasses.replace(cell, traffic={"kind": "kind_test"},
+                               limits={"order_breaks": {"limit": 0}})
+    monkeypatch.setattr(spec, "load_cell", lambda name: cell)
+    assert run.main(["--workload", "w2v-text8.stream", "--seed", "1",
+                     "--seconds", "1", "--trace", "0"]) == 0
+    out = _last_json(capsys)
+    assert out["correct"] is True and out["attempted"] == 3
+    assert out["metrics"]["words_per_s"]["value"] == 2.0

@@ -10,8 +10,10 @@ from harness import serve, spec, train
 
 
 def train_cell(name="w2v-text8.stream") -> spec.Cell:
+    """The training cell at CPU size; its vocabulary is large enough that
+    the checked steps leave some rows untouched."""
     cell = spec.load_cell(name)
-    config = dict(cell.config, vocab_size=2000, corpus_words=1_000_000,
+    config = dict(cell.config, vocab_size=20_000, corpus_words=5_000_000,
                   w2v=dict(cell.config["w2v"], dim=32, max_sentence_len=64,
                            sentences_per_batch=64, prefetch_workers=2))
     traffic = dict(cell.traffic, run_words=40_000, line_words=1000)
